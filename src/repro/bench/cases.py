@@ -53,8 +53,9 @@ def run_p2p(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2,), ("x",))
+    mesh = make_mesh((2,), ("x",))
     comm = Communicator(mesh)
     spec = P("x")
 
@@ -103,9 +104,10 @@ def run_multipair(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
     n = max(ctx.ndev - ctx.ndev % 2, 2)
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator(mesh)
     spec = P("x")
     for k in sorted({1, 2, n // 2}):
@@ -135,8 +137,9 @@ def run_bibw(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2,), ("x",))
+    mesh = make_mesh((2,), ("x",))
     comm = Communicator(mesh)
     spec = P("x")
 
@@ -162,8 +165,9 @@ def run_msgrate(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2,), ("x",))
+    mesh = make_mesh((2,), ("x",))
     comm = Communicator(mesh)
     spec = P("x")
     window = ctx.profile.msgrate_window
@@ -221,9 +225,10 @@ def run_overlap(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
     n = ctx.ndev
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     spec = P("x")
     d = ctx.profile.overlap_compute_dim
     reps = ctx.profile.overlap_compute_iters
@@ -300,13 +305,13 @@ def run_overlap(ctx: BenchContext):
 
 def _rank_sweep(ctx: BenchContext):
     """(mesh, comms, spec, n) per rank count, transports shared."""
-    import jax
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import Communicator
+    from repro.launch.mesh import make_mesh
 
     for n in ctx.rank_counts():
-        mesh = jax.make_mesh((n,), ("r",))
+        mesh = make_mesh((n,), ("r",))
         comms = {name: Communicator(mesh, name)
                  for name in ("native", "tree", "serial")}
         yield n, comms, P("r")
@@ -407,6 +412,8 @@ def run_alltoall(ctx: BenchContext):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.mesh import make_mesh
+
     # --- message-size sweep (the Fig 2/3 discipline applied to the
     # routed-exchange collective OMB-Py benchmarks as a core family)
     for n, comms, spec in _rank_sweep(ctx):
@@ -454,7 +461,7 @@ def run_alltoall(ctx: BenchContext):
 
     pr = ctx.profile
     m = 1 << (ctx.ndev.bit_length() - 1)        # model-axis power of two
-    mesh = jax.make_mesh((1, m), ("data", "model"))
+    mesh = make_mesh((1, m), ("data", "model"))
     E = max(pr.moe_experts // m, 1) * m
     T = max(pr.moe_tokens // m, 1) * m
     key = jax.random.PRNGKey(0)
@@ -485,13 +492,14 @@ def run_grad_exchange(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import CommSpec, Communicator
+    from repro.launch.mesh import make_mesh
     from repro.roofline import hlo as hlo_lib
 
     if ctx.ndev >= 8:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         axes, pod_size, n_pods = ("pod", "data"), 4, 2
     else:  # tiny/test budget: batch-axis-only exchange, no pod level
-        mesh = jax.make_mesh((ctx.ndev,), ("data",))
+        mesh = make_mesh((ctx.ndev,), ("data",))
         axes, pod_size, n_pods = ("data",), ctx.ndev, 1
     ranks = ctx.ndev if ctx.ndev < 8 else 8
     nbytes = ctx.profile.gradex_bytes
@@ -575,12 +583,13 @@ def run_compression(ctx: BenchContext):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import CommSpec, Communicator, CompressionSpec
+    from repro.launch.mesh import make_mesh
 
     if ctx.ndev >= 8:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         axes = ("pod", "data")
     else:  # tiny/test budget: batch-axis-only exchange, no pod level
-        mesh = jax.make_mesh((ctx.ndev,), ("data",))
+        mesh = make_mesh((ctx.ndev,), ("data",))
         axes = ("data",)
     ranks = ctx.ndev if ctx.ndev < 8 else 8
     spec = P(tuple(mesh.axis_names))

@@ -57,13 +57,14 @@ def run_redistribute(ctx: BenchContext):
     from repro.comms import Communicator
     from repro.core import dmat
     from repro.core.dmap import redistribution_plan
+    from repro.launch.mesh import make_mesh
 
     n = max(ctx.ndev, 2)
     shape = tuple(ctx.profile.redist_shape)
     size_bytes = 4
     for s in shape:
         size_bytes *= s
-    mesh = jax.make_mesh((n,), ("r",))
+    mesh = make_mesh((n,), ("r",))
     arr = jnp.arange(float(shape[0] * shape[1]),
                      dtype=jnp.float32).reshape(shape)
 

@@ -338,47 +338,14 @@ class Communicator:
 
         Defaults: replicated in/out (``P()``).  ``manual_axes`` limits
         manual mapping to a subset (e.g. batch axes), leaving the rest to
-        GSPMD — such partial maps must run under ``jax.jit``.  On jax
-        versions whose partial-manual regions cannot lower scheduled
-        primitives (see compat), a rank token is threaded in and the
-        comm ops transparently run their masked-psum emulation.
+        GSPMD — such partial maps must run under ``jax.jit``.
         """
         if in_specs is None:
             in_specs = P()
         if out_specs is None:
             out_specs = P()
-        partial = (manual_axes is not None
-                   and frozenset(manual_axes) != frozenset(
-                       self.mesh.axis_names))
-        if not (partial and compat.PARTIAL_MANUAL_NEEDS_EMULATION):
-            return compat.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                    out_specs=out_specs,
-                                    manual_axes=manual_axes)
-
-        if not isinstance(in_specs, (tuple, list)):
-            raise TypeError(
-                "partial-manual wrap on this jax version threads a rank "
-                "token and needs in_specs as an explicit tuple (one spec "
-                "per argument)")
-        topo = self.topo
-
-        def outer(rank_arr, *args):
-            token = compat.enter_partial_manual(
-                rank_arr[0], topo.axes, topo.axis_sizes)
-            try:
-                return fn(*args)
-            finally:
-                compat.exit_partial_manual(token)
-
-        mapped = compat.shard_map(
-            outer, mesh=self.mesh,
-            in_specs=(P(topo.axes),) + tuple(in_specs),
-            out_specs=out_specs, manual_axes=manual_axes)
-
-        def call(*args):
-            ranks = jnp.arange(topo.n_ranks, dtype=jnp.int32)
-            return mapped(ranks, *args)
-        return call
+        return compat.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                out_specs=out_specs, manual_axes=manual_axes)
 
     def run(self, fn: Callable, *args, in_specs=None, out_specs=None,
             manual_axes: Optional[Sequence[str]] = None):

@@ -1,213 +1,88 @@
-"""Version-portable shims for the handful of jax APIs the comms layer
-builds on.
+"""The wire primitives the comms layer builds on.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the
-top-level namespace and renamed ``check_rep`` to ``check_vma`` along the
-way; ``lax.axis_size`` is similarly recent.  Every module in this repo
-goes through the helpers below instead of importing either spelling
-directly, so a jax upgrade (or downgrade) is a one-file change.
-
-Partial-manual emulation: on the 0.4.x lineage, *partial*-manual
-shard_maps (some mesh axes left to GSPMD — the trainer's gradient
-exchange keeps the model axis automatic) cannot lower ``axis_index`` /
-``ppermute`` / ``all_gather`` / ``psum_scatter`` over the manual axes
-(PartitionId errors or partitioner CHECK-crashes); only ``psum``-family
-reductions survive.  ``Communicator.wrap`` therefore threads a
-data-driven rank token and enters the emulation context below, under
-which the scheduled primitives are rewritten onto masked ``psum`` —
-numerically identical, so explicit comm algorithms keep working under
-partial-manual maps; fully-manual maps (the benchmarks) always use the
-real primitives.
+Every schedule in this package moves data through the five primitives
+below (``psum`` / ``ppermute`` / ``all_gather_tiled`` /
+``all_to_all_blocks`` / ``psum_scatter_blocks``) rather than calling
+``lax`` directly.  They are thin ``lax`` calls plus one hook: while a
+wire-compression context is active (``repro.comms.compression``), an
+in-scope floating payload is handed to the compressor instead, which
+quantizes it, re-enters these primitives with integer payloads + scales,
+and dequantizes.  ``shard_map`` maps the package's ``manual_axes``
+convention onto ``jax.shard_map``'s ``axis_names``.
 """
 from __future__ import annotations
 
 import contextvars
-import inspect
 from typing import Callable, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 from jax import lax
-
-try:                                        # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                         # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
-_CHECK_KW = "check_vma" if "check_vma" in _PARAMS else "check_rep"
-# partial-manual spelling: new API takes the *manual* axes (axis_names=),
-# the experimental API takes the complementary *auto* set (auto=).
-_MANUAL_KW = "axis_names" if "axis_names" in _PARAMS else "auto"
-
-# the experimental-API lineage is the one that cannot lower scheduled
-# primitives inside partial-manual regions
-PARTIAL_MANUAL_NEEDS_EMULATION = _MANUAL_KW == "auto"
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               manual_axes: Optional[Sequence[str]] = None,
               check: bool = False) -> Callable:
-    """`shard_map` under any jax version.
+    """``jax.shard_map`` over ``mesh``.
 
     ``manual_axes`` — axes mapped manually (the body sees per-shard
     blocks and may use collectives over them); every other mesh axis
     stays automatic (GSPMD).  None means fully manual.  Partial-manual
     maps require the call to happen under ``jax.jit``.
     """
-    kwargs = {_CHECK_KW: check}
+    kwargs = {}
     if manual_axes is not None:
         manual = frozenset(manual_axes)
-        rest = frozenset(mesh.axis_names) - manual
-        if rest:
-            kwargs[_MANUAL_KW] = (manual if _MANUAL_KW == "axis_names"
-                                  else rest)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+        if frozenset(mesh.axis_names) - manual:
+            kwargs["axis_names"] = manual
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kwargs)
 
-
-def axis_size(axis) -> int:
-    """Static size of a (possibly composite) mapped axis, inside
-    shard_map.  ``lax.psum(1, axis)`` constant-folds to the size on every
-    jax version; ``lax.axis_size`` only exists on recent ones."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-# ---------------------------------------------------------------------------
-# partial-manual emulation context (see module docstring)
-# ---------------------------------------------------------------------------
-
-_EMU: contextvars.ContextVar = contextvars.ContextVar(
-    "comms_partial_manual_ctx", default=None)
 
 # Wire-compression context (set by repro.comms.compression.compressing):
 # when active, the five wire primitives below hand in-scope floating
-# payloads to the handler, which quantizes, re-enters these primitives
-# with integer payloads + scales, and dequantizes.  compat never imports
-# compression — the dependency points one way.
+# payloads to the handler.  compat never imports compression — the
+# dependency points one way.
 _COMPRESS: contextvars.ContextVar = contextvars.ContextVar(
     "comms_wire_compression", default=None)
 
 
-def enter_partial_manual(rank, axes: Sequence[str], sizes: Sequence[int]):
-    """Activate emulation for the duration of one shard_map body trace.
-    ``rank`` is the traced linear rank (C-order over ``axes``), threaded
-    in as data because ``axis_index`` itself cannot lower."""
-    return _EMU.set({"rank": rank, "axes": tuple(axes),
-                     "sizes": tuple(sizes)})
-
-
-def exit_partial_manual(token) -> None:
-    _EMU.reset(token)
-
-
-def _coord(ctx, axis):
-    """Traced coordinate along one named axis (or linear index over a
-    tuple of axes), derived from the rank token."""
-    axes, sizes = ctx["axes"], ctx["sizes"]
-    if isinstance(axis, (tuple, list)):
-        idx = jnp.zeros((), jnp.int32)
-        for a in axis:
-            idx = idx * sizes[axes.index(a)] + _coord(ctx, a)
-        return idx
-    pos = axes.index(axis)
-    stride = 1
-    for s in sizes[pos + 1:]:
-        stride *= s
-    return (ctx["rank"] // stride) % sizes[pos]
-
-
-def axis_index(axis):
-    """Linear index along a (possibly composite) mapped axis — C-order
-    over the named axes, matching the mesh's rank layout."""
-    ctx = _EMU.get()
-    if ctx is None:
-        return lax.axis_index(axis)
-    return _coord(ctx, axis)
-
-
 def psum(x, axis):
-    """``lax.psum`` that survives partial-manual regions: under
-    emulation, the operand is first tied to the rank token (a no-op
-    ``where``), anchoring its sharding inside the manual subgroup —
-    without this, the 0.4.x partitioner CHECK-fails on operands whose
-    sharding it attributes to the auto region."""
     c = _COMPRESS.get()
     if c is not None and c.applies(axis, x):
         return c.psum(x, axis)
-    ctx = _EMU.get()
-    if ctx is not None:
-        x = jnp.where(ctx["rank"] >= 0, x, jnp.zeros_like(x))
     return lax.psum(x, axis)
 
 
 def ppermute(x, axis, perm):
-    """`lax.ppermute`, or — under emulation — one masked-psum round per
-    (src, dst) pair: dst receives src's payload, non-destinations get
-    zeros (exactly ppermute's semantics)."""
     c = _COMPRESS.get()
     if c is not None and c.applies(axis, x):
         return c.ppermute(x, axis, perm)
-    ctx = _EMU.get()
-    if ctx is None:
-        return lax.ppermute(x, axis, perm)
-    me = _coord(ctx, axis)
-    out = jnp.zeros_like(x)
-    for s, d in perm:
-        contrib = lax.psum(jnp.where(me == s, x, jnp.zeros_like(x)), axis)
-        out = out + jnp.where(me == d, contrib, jnp.zeros_like(x))
-    return out
+    return lax.ppermute(x, axis, perm)
 
 
 def all_gather_tiled(x, axis):
-    """Tiled concat-gather of a flat per-rank block along ``axis`` —
-    emulated as scatter-into-zeros + psum when required."""
+    """Tiled concat-gather of a flat per-rank block along ``axis``."""
     c = _COMPRESS.get()
     if c is not None and c.applies(axis, x):
         return c.all_gather(x, axis)
-    ctx = _EMU.get()
-    if ctx is None:
-        return lax.all_gather(x, axis, axis=0, tiled=True)
-    n = axis_size(axis)
-    me = _coord(ctx, axis)
-    buf = jnp.zeros((n * x.shape[0],) + x.shape[1:], x.dtype)
-    buf = lax.dynamic_update_slice(
-        buf, x, (me * x.shape[0],) + (0,) * (x.ndim - 1))
-    return lax.psum(buf, axis)
+    return lax.all_gather(x, axis, axis=0, tiled=True)
 
 
 def all_to_all_blocks(x, axis, dim=0):
     """Single-axis ``lax.all_to_all`` with split and concat on the same
     dim: ``x`` has one block per destination along ``dim`` (size n =
     ranks on ``axis``); the result holds one block per *source* (block s
-    = rank s's block addressed to this rank).  Emulated as full
-    all-gather + source-column selection when required."""
+    = rank s's block addressed to this rank)."""
     c = _COMPRESS.get()
     if c is not None and c.applies(axis, x):
         return c.all_to_all(x, axis, dim)
-    ctx = _EMU.get()
-    if ctx is None:
-        return lax.all_to_all(x, axis, dim, dim, tiled=False)
-    me = _coord(ctx, axis)
-    full = all_gather_tiled(x.reshape(-1), axis).reshape((-1,) + x.shape)
-    col = lax.dynamic_index_in_dim(full, me, axis=1 + dim, keepdims=False)
-    return jnp.moveaxis(col, 0, dim)
+    return lax.all_to_all(x, axis, dim, dim, tiled=False)
 
 
 def psum_scatter_blocks(x, axis):
     """``lax.psum_scatter`` of ``x`` shaped (n_ranks_along_axis, blk):
-    global sum, each rank keeping its own block — emulated as full psum +
-    dynamic row slice when required."""
+    global sum, each rank keeping its own block."""
     c = _COMPRESS.get()
     if c is not None and c.applies(axis, x):
         return c.psum_scatter(x, axis)
-    ctx = _EMU.get()
-    if ctx is None:
-        return lax.psum_scatter(x, axis, scatter_dimension=0, tiled=False)
-    me = _coord(ctx, axis)
-    full = lax.psum(x, axis)
-    return lax.dynamic_slice(
-        full, (me,) + (0,) * (x.ndim - 1), (1,) + x.shape[1:]
-    ).reshape(x.shape[1:])
+    return lax.psum_scatter(x, axis, scatter_dimension=0, tiled=False)

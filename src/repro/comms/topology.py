@@ -15,9 +15,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence, Tuple
 
+from jax import lax
 from jax.sharding import Mesh
-
-from repro.comms import compat
 
 POD_AXIS = "pod"
 
@@ -73,8 +72,8 @@ class Topology:
     # ------------------------------------------------- traced (in-shard_map)
     def rank(self):
         """Linear rank of the calling shard (traced value)."""
-        return compat.axis_index(self.axes)
+        return lax.axis_index(self.axes)
 
     def size(self) -> int:
         """Rank count as seen inside shard_map (== n_ranks)."""
-        return compat.axis_size(self.axes)
+        return lax.axis_size(self.axes)

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.comms.compat import (all_gather_tiled as _all_gather,
-                                axis_index as _axis_index,
-                                axis_size as _axis_size,
                                 ppermute as _ppermute,
                                 psum as _psum,
                                 psum_scatter_blocks as _psum_scatter)
@@ -47,8 +45,8 @@ def tree_bcast_axis(x: Array, axis: str, root: int = 0) -> Array:
 
     The value on rank ``root`` wins; other ranks' payloads are ignored.
     log2(n) ppermute rounds — the paper's optimized broadcast."""
-    n = _axis_size(axis)
-    me = _axis_index(axis)
+    n = lax.axis_size(axis)
+    me = lax.axis_index(axis)
     have = (me == root)
     for rnd in topology.tree_bcast_rounds(n, root):
         recv = _ppermute(x, axis, rnd)
@@ -63,8 +61,8 @@ def tree_bcast_axis(x: Array, axis: str, root: int = 0) -> Array:
 def serial_bcast_axis(x: Array, axis: str, root: int = 0) -> Array:
     """The paper's initial serialized broadcast: n-1 rounds, root sends to
     one rank per round."""
-    n = _axis_size(axis)
-    me = _axis_index(axis)
+    n = lax.axis_size(axis)
+    me = lax.axis_index(axis)
     for rnd in topology.serial_bcast_rounds(n, root):
         recv = _ppermute(x, axis, rnd)
         (src, dst), = rnd
@@ -75,10 +73,10 @@ def serial_bcast_axis(x: Array, axis: str, root: int = 0) -> Array:
 def tree_reduce_axis(x: Array, axis: str, root: int = 0) -> Array:
     """Binary-tree sum-reduction to ``root`` along one axis (the reduce
     flavour of the paper's agg)."""
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     for rnd in topology.tree_gather_rounds(n, root):
         recv = _ppermute(x, axis, rnd)
-        me = _axis_index(axis)
+        me = lax.axis_index(axis)
         dsts = jnp.array([d for _, d in rnd], jnp.int32)
         is_dst = jnp.any(me == dsts)
         x = jnp.where(is_dst, x + recv, x)
@@ -89,8 +87,8 @@ def tree_gather_axis(x: Array, axis: str, root: int = 0) -> Array:
     """Binary-tree concat-gather to ``root`` (paper Fig 4 agg): message
     doubles each round, exactly the paper's growing aggregation buffers.
     Returns (n*shard,) on root; junk elsewhere (masked by caller)."""
-    n = _axis_size(axis)
-    me = _axis_index(axis)
+    n = lax.axis_size(axis)
+    me = lax.axis_index(axis)
     flat = x.reshape(-1)
     local = flat.shape[0]
     buf = flat
@@ -130,10 +128,10 @@ def pairwise_alltoall_axis(x: Array, axis: str, *, dim: int = 0,
     shim), so a wire-compression context quantizes them without this
     schedule knowing.
     """
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
-    me = _axis_index(axis)
+    me = lax.axis_index(axis)
 
     def exchange(blk, perm):
         return _ppermute(blk, axis, perm)
@@ -163,8 +161,8 @@ def pairwise_alltoall_axis(x: Array, axis: str, *, dim: int = 0,
 def ring_allgather_axis(x: Array, axis: str) -> Array:
     """Ring all-gather via n-1 ppermutes (bandwidth-optimal reference for
     the benchmark harness)."""
-    n = _axis_size(axis)
-    me = _axis_index(axis)
+    n = lax.axis_size(axis)
+    me = lax.axis_index(axis)
     flat = x.reshape(-1)
     local = flat.shape[0]
     out = jnp.zeros((n, local), x.dtype)
@@ -186,7 +184,7 @@ def _axis_roots(root: int, axes: Sequence[str]) -> dict:
     """Decompose a *global* (linear, C-order over ``axes``) root rank
     into its per-axis coordinates — the root each per-axis schedule
     needs.  Sizes are static inside shard_map."""
-    sizes = [_axis_size(a) for a in axes]
+    sizes = [lax.axis_size(a) for a in axes]
     coords = {}
     for a, n in zip(reversed(tuple(axes)), reversed(sizes)):
         coords[a] = root % n
@@ -237,7 +235,7 @@ def hier_allreduce_local(x: Array, *, pod_axis: Optional[str],
     flat = x.reshape(-1)
     n_in = 1
     for a in in_axes:
-        n_in *= _axis_size(a)
+        n_in *= lax.axis_size(a)
     if flat.shape[0] % n_in or n_in == 1:
         y = _psum(x, tuple(in_axes))
         if pod_axis is not None:

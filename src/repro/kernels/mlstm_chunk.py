@@ -27,11 +27,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions;
-# fail at import (AttributeError names the missing symbol) if neither exists
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG = -1e30
 
 
@@ -142,7 +137,7 @@ def mlstm_chunk(q, k, v, li, lf, *, chunk: int = 128,
             pltpu.VMEM((1, dh), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, bc, li4)

@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 ``attention`` dispatches to the flash kernel on TPU (or when forced via
-``use_kernel=True``, e.g. interpret-mode tests) and to the pure-jnp
-reference otherwise — the dry-run on the CPU backend lowers the XLA
-path, the kernel is the TPU deployment path (see DESIGN.md §2).
+``use_kernel=True``) and to the pure-jnp reference otherwise.  The
+kernel runs in Pallas interpret mode only when the caller passes
+``interpret=True`` (the CPU kernel tests); off the TPU a forced kernel
+without it fails to lower instead of silently running interpreted.
 """
 from __future__ import annotations
 
@@ -24,5 +25,5 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     if use_kernel or on_tpu():
         return flash_attention(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
-                               interpret=interpret or not on_tpu())
+                               interpret=interpret)
     return ref.attention_ref(q, k, v, causal=causal, window=window)

@@ -36,10 +36,12 @@ def main() -> None:
     import jax
     import numpy as np
     from repro.configs.base import get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import mesh_for_devices
     from repro.models.model import Model
     from repro.serve import Engine, Request
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -48,10 +48,12 @@ def main() -> None:
 
     import jax
     from repro.configs.base import SHAPES, ShapeSpec, get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import (make_local_mesh, make_production_mesh,
                                    mesh_for_devices)
     from repro.train.trainer import Trainer, TrainerConfig
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]
     if args.reduced:

@@ -6,7 +6,7 @@ xLSTM's mLSTM/sLSTM interleave) become superblock structure, so the HLO
 stays O(1) in depth — essential for the 512-device dry-run sweep.
 
 Public surface:
-    Model(cfg, mesh)   .init  .train_loss  .prefill  .decode_step
+    Model(cfg, mesh)   .init  .train_loss  .logits  .prefill  .decode_step
                        .serve_step  .reset_cache_slots
                        .cache_specs  .param_specs (see partition.py)
 """
@@ -486,17 +486,29 @@ class Model:
         return params["unemb"]
 
     # --- public entry points ---------------------------------------------------
-    def train_loss(self, params, batch) -> Tuple[Array, Dict[str, Array]]:
-        """Loss for one microbatch: batch = {'tokens','labels', [extras]}."""
-        tokens = batch["tokens"]
+    def _causal_forward(self, params, tokens, ctx):
+        """Cache-free forward: final-normed hidden states and aux loss."""
         B, S = tokens.shape
         h = jnp.take(params["emb"], tokens, axis=0)
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-        ctx = self._ctx_from(params, batch)
         h, _, aux = self._backbone(params, h, pos, {}, ctx, "train")
+        return h, aux
+
+    def train_loss(self, params, batch) -> Tuple[Array, Dict[str, Array]]:
+        """Loss for one microbatch: batch = {'tokens','labels', [extras]}."""
+        h, aux = self._causal_forward(params, batch["tokens"],
+                                      self._ctx_from(params, batch))
         loss = softmax_xent_chunked(h, self._unemb(params), batch["labels"])
         total = loss + 0.01 * aux
         return total, {"xent": loss, "aux": aux}
+
+    def logits(self, params, tokens):
+        """Cache-free causal forward over whole sequences (the reference
+        the cached serving paths are checked against): tokens (B, S) ->
+        float32 logits (B, S, V).  Positions past a sequence's end only
+        see earlier tokens, so right-padding leaves the prefix exact."""
+        h, _ = self._causal_forward(params, tokens, {"media": None})
+        return logits_for(h, self._unemb(params))
 
     def _ctx_from(self, params, batch):
         ctx: Dict[str, Any] = {"media": None}

@@ -46,8 +46,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comms.communicator import CommSpec, Communicator
-from repro.comms.compat import (axis_index, axis_size,
-                                shard_map)
+from repro.comms.compat import shard_map
 
 Array = jax.Array
 
@@ -124,7 +123,7 @@ def _gather_fsdp(w: Array, axis: int, fsdp_axes: Sequence[str],
     for a in fsdp_axes:
         q = lax.all_gather(q, a, axis=axis, tiled=True)
         scale = lax.all_gather(scale, a, axis=axis, tiled=True)
-        nsh *= axis_size(a)
+        nsh *= lax.axis_size(a)
     shp = q.shape
     split = shp[:axis] + (nsh, blk) + shp[axis + 1:]
     qs = q.reshape(split).astype(jnp.bfloat16)
@@ -216,7 +215,7 @@ def _moe_replicated_local(x: Array, wr: Array, w1: Array, w3: Array,
     Tl, D = x.shape
     M, E = model_size, num_experts
     E_loc = E // M
-    my = axis_index(model_axis)
+    my = lax.axis_index(model_axis)
     w1 = _gather_fsdp(w1, 2, fsdp_axes, gather_dtype)
     w3 = _gather_fsdp(w3, 2, fsdp_axes, gather_dtype)
     w2 = _gather_fsdp(w2, 1, fsdp_axes, gather_dtype)

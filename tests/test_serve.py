@@ -248,6 +248,41 @@ def test_truncation_is_reported_not_silent(stack, paged_engine):
     assert paged_engine.pool.used_blocks == 0
 
 
+def test_two_paged_entries_share_one_undonated_block_table():
+    # local windows >= max_len: both sub-blocks of the local/global group
+    # are paged, and every tick donates the cache with both entries in it
+    import jax
+    from repro.configs.base import get_config, reduced
+    from repro.launch.mesh import mesh_for_devices
+    from repro.models.model import Model
+
+    cfg = reduced(get_config("gemma3-4b"), sliding_window=32)
+    mesh = mesh_for_devices(1)
+    stack2 = (cfg, mesh, Model(cfg, mesh).init(jax.random.PRNGKey(0)))
+    paged = _engine(stack2, cache_mode="paged")
+    assert len(paged.paged_entries) >= 2
+    assert all("btab" not in ent for ent in paged.cache.values())
+    res_p = paged.run_to_completion(_reqs(stack2))
+    res_d = _engine(stack2, cache_mode="dense").run_to_completion(
+        _reqs(stack2))
+    assert not res_p.truncated and sorted(res_p) == [0, 1, 2, 3]
+    assert all(res_p[rid] == res_d[rid] for rid in res_p)
+
+
+def test_upload_copies_host_buffer():
+    # a 64-byte-aligned numpy buffer is the case the CPU backend aliases
+    import jax.numpy as jnp
+    from repro.serve.engine import _upload
+
+    raw = np.zeros(8 + 64, np.uint8)
+    off = (-raw.ctypes.data) % 64
+    mask = raw[off:off + 8].view(bool)
+    dev = _upload(mask)
+    mask[:] = True                        # host edit after the upload
+    assert not np.asarray(dev).any()
+    assert isinstance(dev, jnp.ndarray)
+
+
 def test_legacy_path_serves_recurrent_arch():
     import jax
     from repro.configs.base import get_config, reduced
