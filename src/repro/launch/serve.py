@@ -1,5 +1,7 @@
 """Serving launcher: load (or randomly init) a model and serve a batch of
-synthetic requests through the engine, reporting tokens/sec and p95 TTFT.
+synthetic requests through the engine, reporting tokens/sec and p95 TTFT,
+and the engine's counters: chunk fill, queue-wait p90, host time per
+tick, decode tokens per tick, slot resets and block-table uploads.
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma3-4b --reduced
 """
@@ -86,6 +88,20 @@ def main() -> None:
         p95 = ttfts[min(len(ttfts) - 1, int(0.95 * len(ttfts)))]
         print(f"[serve] {done_tokens / elapsed:.0f} tok/s, "
               f"p95 TTFT {p95 * 1e3:.1f} ms")
+    st = engine.stats
+    if st.dispatched:
+        waits = [m["admit_s"] - m["arrival_s"]
+                 for m in results.metrics.values()]
+        fill = 100.0 * st.chunk_tokens / st.chunk_rows if st.chunk_rows \
+            else 0.0
+        print(f"[serve] {st.dispatched} ticks {st.ticks}, chunk fill "
+              f"{fill:.1f}%, queue wait p90 "
+              f"{np.percentile(waits, 90) * 1e3:.1f} ms, host "
+              f"{st.host_s / st.dispatched * 1e3:.2f} ms/tick")
+        print(f"[serve] {st.decode_tokens} decode tokens "
+              f"({st.decode_tokens / st.dispatched:.2f}/tick of "
+              f"{engine.slots} slots), {st.resets} slot resets, "
+              f"{st.btab_uploads} block-table uploads")
     if engine.pool is not None:
         print(f"[serve] pool high water {engine.pool.high_water}/"
               f"{engine.pool.num_blocks} blocks "

@@ -132,12 +132,13 @@ def paged_gather(buf: Array, btab: Array) -> Array:
     token position p of slot b lives at index p; unleased blocks read as
     zeros (their ``pos`` entries are -1, so attention masks them out).
     """
-    bs = buf.shape[1]
-    flat = _flat_pool(buf)
-    base = jnp.where(btab >= 0, btab * bs, flat.shape[0])     # OOB => fill
-    idx = base[:, :, None] + jnp.arange(bs, dtype=btab.dtype)[None, None]
-    idx = idx.reshape(btab.shape[0], -1)
-    return jnp.take(flat, idx, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope("paged_gather"):
+        bs = buf.shape[1]
+        flat = _flat_pool(buf)
+        base = jnp.where(btab >= 0, btab * bs, flat.shape[0])  # OOB => fill
+        idx = base[:, :, None] + jnp.arange(bs, dtype=btab.dtype)[None, None]
+        idx = idx.reshape(btab.shape[0], -1)
+        return jnp.take(flat, idx, axis=0, mode="fill", fill_value=0)
 
 
 def paged_scatter(buf: Array, btab: Array, new: Array, q_pos: Array
@@ -149,16 +150,17 @@ def paged_scatter(buf: Array, btab: Array, new: Array, q_pos: Array
     position is invalid or whose logical block is unleased are dropped —
     they can never land in another slot's blocks.
     """
-    bs = buf.shape[1]
-    flat = _flat_pool(buf)
-    size = flat.shape[0]
-    lb = jnp.where(q_pos >= 0, q_pos // bs, 0)
-    blk = jnp.take_along_axis(btab, lb, axis=1)               # (B, C)
-    phys = jnp.where((q_pos >= 0) & (blk >= 0),
-                     blk * bs + q_pos % bs, size)             # size => drop
-    flat = flat.at[phys.reshape(-1)].set(
-        new.reshape((-1,) + new.shape[2:]), mode="drop")
-    return flat.reshape(buf.shape)
+    with jax.named_scope("paged_scatter"):
+        bs = buf.shape[1]
+        flat = _flat_pool(buf)
+        size = flat.shape[0]
+        lb = jnp.where(q_pos >= 0, q_pos // bs, 0)
+        blk = jnp.take_along_axis(btab, lb, axis=1)           # (B, C)
+        phys = jnp.where((q_pos >= 0) & (blk >= 0),
+                         blk * bs + q_pos % bs, size)         # size => drop
+        flat = flat.at[phys.reshape(-1)].set(
+            new.reshape((-1,) + new.shape[2:]), mode="drop")
+        return flat.reshape(buf.shape)
 
 
 def paged_kv_entry(count: int, num_blocks: int, block_size: int,

@@ -36,6 +36,15 @@ Cache modes:
 Cross-host: admission goes through a Communicator agg+bcast agreement
 round (:func:`~repro.serve.scheduler.agree_admission_count`); load and
 drain are Communicator barriers.
+
+Tracing is always on and costs about a microsecond per span with no
+profiler running.  Each loop iteration opens ``jax.profiler``
+``TraceAnnotation`` spans (``serve.admit``, ``serve.plan``,
+``serve.prepare``, ``serve.dispatch``, ``serve.readback``,
+``serve.record``, ``serve.wait``) that carry the tick's id (``tick``, the
+engine's dispatch counter) and land on the device trace's clock when a
+profiler runs; :class:`EngineStats` counts ticks, tokens and host
+seconds for every ``run_trace``.
 """
 from __future__ import annotations
 
@@ -75,8 +84,11 @@ class ServeResult(dict):
       drained (the old engine silently dropped this);
     * ``unfinished`` — ``{rid: partial tokens}`` for in-flight and
       never-admitted requests at truncation;
-    * ``metrics`` — ``{rid: {arrival_s, ttft_s, done_s, tokens}}``
-      (host-observed; TTFT includes the one-tick pipeline lag).
+    * ``metrics`` — ``{rid: {arrival_s, admit_s, ttft_s, done_s,
+      tokens}}`` for every admitted request (host seconds since the run
+      began; ``admit_s`` is when it took a slot, 0.0 for one that held
+      a slot when the run began; TTFT includes the one-tick pipeline
+      lag; ``ttft_s`` is None and ``done_s`` absent until they happen).
     """
 
     def __init__(self, done, truncated: bool, unfinished, metrics):
@@ -84,6 +96,34 @@ class ServeResult(dict):
         self.truncated = truncated
         self.unfinished = dict(unfinished)
         self.metrics = dict(metrics)
+
+
+@dataclasses.dataclass
+class EngineStats:
+    """Counters of one ``run_trace`` (reset when it starts).
+
+    ``host_s`` is the loop's own time: wall seconds in the loop outside
+    blocking read-backs and arrival sleeps.  Every field is a plain host
+    number, so a copy taken mid-run is a consistent snapshot of the
+    iterations before it."""
+
+    ticks: Dict[str, int] = dataclasses.field(default_factory=dict)
+    chunk_tokens: int = 0      # prompt tokens carried by chunk ticks
+    chunk_rows: int = 0        # their row capacity: slots x chunk width
+    decode_tokens: int = 0
+    resets: int = 0            # slot-reset programs dispatched
+    btab_uploads: int = 0      # block-table uploads
+    loop_s: float = 0.0        # wall seconds of the loop so far
+    readback_s: float = 0.0    # blocked reading a tick's tokens back
+    wait_s: float = 0.0        # asleep until the next arrival
+
+    @property
+    def host_s(self) -> float:
+        return self.loop_s - self.readback_s - self.wait_s
+
+    @property
+    def dispatched(self) -> int:
+        return sum(self.ticks.values())
 
 
 def _supports_batched(cfg: ArchConfig) -> bool:
@@ -160,7 +200,8 @@ class Engine:
         self.requests: Dict[int, Request] = {}
         self._done: Dict[int, List[int]] = {}
         self._metrics: Dict[int, dict] = {}
-        self._arrival: Dict[int, float] = {}
+        self.stats = EngineStats()
+        self._tick = 0                    # id of the next dispatched tick
         self._reset_mask = np.zeros((slots,), bool)
         self.btab = None
         self._dispatch_fn = jax.jit(self._dispatch_body,
@@ -278,7 +319,8 @@ class Engine:
         cap = self._cap_for(req)
         req.out_tokens = []
         self.requests[req.rid] = req
-        self._arrival[req.rid] = arrival_s
+        self._metrics[req.rid] = {"arrival_s": arrival_s,
+                                  "admit_s": self._now(), "ttft_s": None}
         if cap <= 0:                      # nothing to generate
             self._finalize(req.rid, arrival_s)
             return
@@ -320,12 +362,13 @@ class Engine:
         arrived = [r for (t, r) in queue if t <= now]
         if not arrived:
             return
-        n = self._admittable(arrived)
-        n = agree_admission_count(self.comm, n)
-        for req in arrived[:n]:
-            idx = next(i for i, (_, r) in enumerate(queue) if r is req)
-            arr, _ = queue.pop(idx)
-            self._admit_one(req, arr)
+        with jax.profiler.TraceAnnotation("serve.admit", tick=self._tick):
+            n = self._admittable(arrived)
+            n = agree_admission_count(self.comm, n)
+            for req in arrived[:n]:
+                idx = next(i for i, (_, r) in enumerate(queue) if r is req)
+                arr, _ = queue.pop(idx)
+                self._admit_one(req, arr)
 
     # ----------------------------------------------------------------- ticks
     def _now(self) -> float:
@@ -337,6 +380,7 @@ class Engine:
                                         _upload(self._reset_mask),
                                         self.btab)
             self._reset_mask[:] = False
+            self.stats.resets += 1
         if self.pool is not None:
             for i in range(self.slots):
                 if plan.lengths[i] > 0:
@@ -345,29 +389,50 @@ class Engine:
             if self.pool.dirty:
                 self.btab = _upload(self.pool.table)
                 self.pool.dirty = False
+                self.stats.btab_uploads += 1
+
+    def _count(self, plan: TickPlan) -> None:
+        """Add ``plan`` to the counters."""
+        st = self.stats
+        st.ticks[plan.kind] = st.ticks.get(plan.kind, 0) + 1
+        decode = int(plan.use_next.sum())      # a decode row feeds 1 token
+        st.decode_tokens += decode
+        if plan.kind == "chunk":
+            st.chunk_tokens += int(plan.lengths.sum()) - decode
+            st.chunk_rows += plan.tokens.size
 
     def _dispatch(self, plan: TickPlan):
-        self._pre_dispatch(plan)
-        self.key, sub = jax.random.split(self.key)
-        nxt, self.next_buf, self.cache = self._dispatch_fn(
-            self.params, jnp.asarray(plan.tokens),
-            jnp.asarray(plan.use_next), jnp.asarray(plan.starts),
-            jnp.asarray(plan.lengths), _upload(self.temps), sub,
-            self.next_buf, self.cache, self.btab)
+        plan.tick = tick = self._tick
+        self._tick += 1
+        with jax.profiler.TraceAnnotation("serve.prepare", tick=tick):
+            self._pre_dispatch(plan)
+        self._count(plan)
+        with jax.profiler.TraceAnnotation("serve.dispatch", tick=tick,
+                                          kind=plan.kind):
+            self.key, sub = jax.random.split(self.key)
+            nxt, self.next_buf, self.cache = self._dispatch_fn(
+                self.params, jnp.asarray(plan.tokens),
+                jnp.asarray(plan.use_next), jnp.asarray(plan.starts),
+                jnp.asarray(plan.lengths), _upload(self.temps), sub,
+                self.next_buf, self.cache, self.btab)
         return nxt
 
     def _finish(self, plan: TickPlan, nxt) -> Dict[int, int]:
         """Host bookkeeping for a completed tick (blocks on the device)."""
-        toks = np.asarray(nxt)
-        now = self._now()
-        out: Dict[int, int] = {}
-        for slot, epoch, gidx in plan.samples:
-            st = self.sched.states[slot]
-            if st is None or st.epoch != epoch:
-                continue              # slot released mid-flight (EOS)
-            tok = int(toks[slot])
-            out[st.rid] = tok
-            self._record(slot, epoch, gidx, tok, now)
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.readback", tick=plan.tick):
+            toks = np.asarray(nxt)
+        self.stats.readback_s += time.perf_counter() - t
+        with jax.profiler.TraceAnnotation("serve.record", tick=plan.tick):
+            now = self._now()
+            out: Dict[int, int] = {}
+            for slot, epoch, gidx in plan.samples:
+                st = self.sched.states[slot]
+                if st is None or st.epoch != epoch:
+                    continue          # slot released mid-flight (EOS)
+                tok = int(toks[slot])
+                out[st.rid] = tok
+                self._record(slot, epoch, gidx, tok, now)
         return out
 
     def _record(self, slot: int, epoch: int, gidx: int, tok: int,
@@ -377,9 +442,8 @@ class Engine:
         req.out_tokens.append(tok)
         st.recorded = gidx + 1
         if gidx == 0:
-            self._metrics[st.rid] = {
-                "arrival_s": self._arrival[st.rid],
-                "ttft_s": now - self._arrival[st.rid]}
+            m = self._metrics[st.rid]
+            m["ttft_s"] = now - m["arrival_s"]
         hit_eos = st.eos_id is not None and tok == st.eos_id
         if hit_eos:
             st.done = True
@@ -397,11 +461,9 @@ class Engine:
     def _finalize(self, rid: int, now: float) -> None:
         req = self.requests.pop(rid)
         self._done[rid] = req.out_tokens
-        m = self._metrics.setdefault(
-            rid, {"arrival_s": self._arrival[rid], "ttft_s": None})
+        m = self._metrics[rid]
         m["done_s"] = now
         m["tokens"] = len(req.out_tokens)
-        self._arrival.pop(rid, None)
 
     def step(self) -> Dict[int, int]:
         """Plan + dispatch + finish one tick synchronously; returns
@@ -438,34 +500,48 @@ class Engine:
                         f"request {r.rid} needs {worst} blocks but the "
                         f"pool holds {self.pool.num_blocks}")
         self._t0 = time.perf_counter()
-        self._done, self._metrics = {}, {}
+        self._done = {}
+        self._metrics = {rid: m for rid, m in self._metrics.items()
+                         if rid in self.requests}      # still in flight
+        for m in self._metrics.values():   # took its slot before this run
+            m["admit_s"] = 0.0
+        self.stats = stats = EngineStats()
         queue = sorted(zip(arrivals_s, reqs), key=lambda p: p[0])
         inflight = None
         steps = 0
-        while steps < max_steps:
-            self._admit_arrived(queue, self._now())
-            plan = self.sched.plan()
-            if plan is None:
+        try:
+            while steps < max_steps:
+                stats.loop_s = self._now()
+                self._admit_arrived(queue, stats.loop_s)
+                with jax.profiler.TraceAnnotation("serve.plan",
+                                                  tick=self._tick):
+                    plan = self.sched.plan()
+                if plan is None:
+                    if inflight is not None:
+                        self._finish(*inflight)     # may free slots
+                        inflight = None
+                        continue
+                    if queue:
+                        wait = queue[0][0] - self._now()
+                        if wait > 0:
+                            t = time.perf_counter()
+                            with jax.profiler.TraceAnnotation("serve.wait"):
+                                time.sleep(min(wait, 1e-3))
+                            stats.wait_s += time.perf_counter() - t
+                        continue
+                    break
+                nxt = self._dispatch(plan)
+                steps += 1
                 if inflight is not None:
-                    self._finish(*inflight)     # may free slots
+                    self._finish(*inflight)
+                inflight = (plan, nxt)
+                if not self.overlap:
+                    self._finish(*inflight)
                     inflight = None
-                    continue
-                if queue:
-                    wait = queue[0][0] - self._now()
-                    if wait > 0:
-                        time.sleep(min(wait, 1e-3))
-                    continue
-                break
-            nxt = self._dispatch(plan)
-            steps += 1
             if inflight is not None:
                 self._finish(*inflight)
-            inflight = (plan, nxt)
-            if not self.overlap:
-                self._finish(*inflight)
-                inflight = None
-        if inflight is not None:
-            self._finish(*inflight)
+        finally:
+            stats.loop_s = self._now()
         self.comm.sync()       # drain: all ranks idle before returning
         unfinished = {st.rid: list(self.requests[st.rid].out_tokens)
                       for _, st in self.sched.active()}

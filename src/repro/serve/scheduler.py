@@ -81,6 +81,7 @@ class TickPlan:
     starts: np.ndarray              # (B,) int32
     lengths: np.ndarray             # (B,) int32 (0 = idle row)
     samples: List[Tuple[int, int, int]]  # (slot, epoch, gen_index)
+    tick: int = -1                  # the engine's id for it, once dispatched
 
 
 class Scheduler:

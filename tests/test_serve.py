@@ -306,6 +306,137 @@ def test_legacy_path_serves_recurrent_arch():
     assert all(len(v) == 3 for v in res.values())
 
 
+# ------------------------------------------- spans, counters and scopes
+
+
+def _host_spans(trace_dir):
+    """``[(name, stats)]`` of the ``serve.*`` host events a profiler
+    wrote under ``trace_dir``, in start order."""
+    import glob
+
+    import jax
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb",
+                            recursive=True))[-1]
+    evs = [(e.start_ns, e.name, dict(e.stats))
+           for plane in jax.profiler.ProfileData.from_file(path).planes
+           for line in plane.lines for e in line.events
+           if e.name.startswith("serve.")]
+    return [(name, stats) for _, name, stats in sorted(evs)]
+
+
+def _recording_plans(monkeypatch, eng):
+    plans = []
+    inner = eng.sched.plan
+
+    def plan():
+        p = inner()
+        if p is not None:
+            plans.append(p)
+        return p
+
+    monkeypatch.setattr(eng.sched, "plan", plan)
+    return plans
+
+
+def test_run_trace_writes_every_span_with_tick_ids(stack, paged_engine,
+                                                   tmp_path):
+    import jax
+
+    paged_engine.run_to_completion(_reqs(stack, lens=(5, 9)))  # compiled
+    reqs = _reqs(stack, lens=(5, 9, 3), new=3)
+    jax.profiler.start_trace(str(tmp_path))
+    try:                                  # the loop waits for arrivals
+        res = paged_engine.run_trace(reqs, [0.05, 0.05, 0.1])
+    finally:
+        jax.profiler.stop_trace()
+    assert not res.truncated and sorted(res) == [0, 1, 2]
+    spans = _host_spans(tmp_path)
+    names = {n for n, _ in spans}
+    assert names == {"serve.admit", "serve.plan", "serve.prepare",
+                     "serve.dispatch", "serve.readback", "serve.record",
+                     "serve.wait"}
+    ticks = {n: [st["tick"] for m, st in spans if m == n] for n in names
+             if n != "serve.wait"}
+    dispatched = ticks["serve.dispatch"]
+    assert dispatched == list(range(dispatched[0], dispatched[0]
+                                    + len(dispatched)))
+    assert ticks["serve.prepare"] == dispatched
+    assert sorted(ticks["serve.readback"]) == dispatched   # every tick read
+    assert ticks["serve.record"] == ticks["serve.readback"]
+    assert set(ticks["serve.plan"]) <= set(dispatched + [dispatched[-1] + 1])
+    assert {st["kind"] for m, st in spans if m == "serve.dispatch"} == \
+        {"chunk", "decode"}
+
+
+def test_stats_add_up_against_the_plans(stack, paged_engine, monkeypatch):
+    plans = _recording_plans(monkeypatch, paged_engine)
+    lens = (5, 9, 3, 7)
+    res = paged_engine.run_trace(_reqs(stack, lens=lens, new=4),
+                                 [0.02, 0.02, 0.03, 0.05])
+    assert not res.truncated
+    st = paged_engine.stats
+    kinds = {k: sum(p.kind == k for p in plans) for k in ("chunk", "decode")}
+    assert st.ticks == {k: n for k, n in kinds.items() if n}
+    assert st.dispatched == len(plans)
+    chunks = [p for p in plans if p.kind == "chunk"]
+    assert st.chunk_tokens == sum(int(p.lengths.sum()) for p in chunks) \
+        == sum(lens)
+    assert st.chunk_rows == len(chunks) * paged_engine.slots \
+        * paged_engine.sched.chunk
+    assert st.decode_tokens == sum(int(p.lengths.sum()) for p in plans
+                                   if p.kind == "decode") == 4 * 3
+    assert st.btab_uploads >= 1 and st.resets >= 1
+    assert st.readback_s > 0 and st.host_s > 0
+    assert st.wait_s >= 0.015             # asleep until the first arrival
+    assert st.loop_s > st.readback_s + st.wait_s
+
+
+def test_admit_s_lies_between_arrival_and_first_token(stack, paged_engine):
+    res = paged_engine.run_trace(_reqs(stack, lens=(5, 9, 3, 7), new=3),
+                                 [0.0, 0.0, 0.01, 0.04])
+    assert sorted(res.metrics) == [0, 1, 2, 3]
+    for m in res.metrics.values():
+        assert m["arrival_s"] <= m["admit_s"] <= m["arrival_s"] \
+            + m["ttft_s"] <= m["done_s"]
+
+
+def test_request_admitted_before_a_run_keeps_its_record(stack,
+                                                        paged_engine):
+    first, second = _reqs(stack, lens=(5, 9), new=3)
+    assert paged_engine.admit(first)
+    res = paged_engine.run_to_completion([second])
+    assert sorted(res) == [0, 1]
+    assert {rid: m["tokens"] for rid, m in res.metrics.items()} == \
+        {0: 3, 1: 3}
+    first_m = res.metrics[0]              # on this run's clock
+    assert first_m["arrival_s"] == first_m["admit_s"] == 0.0
+    assert 0.0 < first_m["ttft_s"] <= first_m["done_s"]
+
+
+@pytest.mark.parametrize("width", [1, 4])
+def test_paged_tick_program_names_its_gather_and_scatter(stack,
+                                                         paged_engine,
+                                                         width):
+    """The tick program's ops carry the paged cache's scopes in their
+    ``op_name`` metadata, in the decode (width 1) and chunk programs."""
+    import re
+
+    import jax.numpy as jnp
+    from repro.serve.engine import _upload
+
+    eng = paged_engine
+    B = eng.slots
+    text = eng._dispatch_fn.lower(
+        eng.params, jnp.zeros((B, width), jnp.int32),
+        jnp.zeros((B,), bool), jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B,), jnp.int32), _upload(eng.temps), eng.key,
+        eng.next_buf, eng.cache, eng.btab).compile().as_text()
+    scopes = set(re.findall(r'op_name="[^"]*/(paged_gather|paged_scatter)/',
+                            text))
+    assert scopes == {"paged_gather", "paged_scatter"}
+
+
 # ------------------------------------------------- multi-rank drain (2dev)
 
 DRAIN = """
