@@ -85,23 +85,33 @@ def self_attention(p, x, pos, kv, *, heads, kv_heads, dh, window, theta,
                    ) -> Tuple[Array, Optional[dict]]:
     """x: (B, C, D); pos: (B, C); kv: {'k','v'} (B,W,...) + group-level pos
     handled by the caller (passed as kv['pos']).  A paged entry carries a
-    block table in kv['btab'] and its k/v are the shared physical pool
-    (num_blocks, bs, H, dh) instead of per-slot rings — same update
-    discipline, reads/writes go through the table."""
+    block table in kv['btab'] and the layer's index in kv['layer'], and
+    its k/v are the whole stacked physical pool (count, num_blocks, bs,
+    *row; see ``cache.pool_row``) instead of per-slot rings; the returned
+    k/v are that pool with this layer's tokens written in place.  Same
+    update discipline; reads and writes go through the table at the
+    layer's index."""
     xn = rmsnorm(p["norm"], x)
     q, k, v = _qkv(p, xn, xn, heads, kv_heads, dh)
     q = rope(q, pos, theta)
     k = rope(k, pos, theta)
     new_kv = None
     paged = kv is not None and "btab" in kv
+    if paged:
+        btab, layer = kv["btab"], kv["layer"]
+
+        def gather(pool):
+            rows = cache_lib.paged_gather(pool, btab, layer)
+            return rows.reshape(rows.shape[:2] + (kv_heads, dh))
+
+        def scatter(pool, new):
+            return cache_lib.paged_scatter(pool, btab, layer, new, pos)
     if mode == "train":
         out = attention(q, k, v, pos, pos, window=window, causal=True,
                         q_chunk=q_chunk, logits_dtype=logits_dtype)
     elif mode == "chunk":
-        old_k = cache_lib.paged_gather(kv["k"], kv["btab"]) if paged \
-            else kv["k"]
-        old_v = cache_lib.paged_gather(kv["v"], kv["btab"]) if paged \
-            else kv["v"]
+        old_k = gather(kv["k"]) if paged else kv["k"]
+        old_v = gather(kv["v"]) if paged else kv["v"]
         keys = jnp.concatenate([old_k, k], axis=1)
         vals = jnp.concatenate([old_v, v], axis=1)
         k_pos = jnp.concatenate([kv["pos"], pos], axis=1)
@@ -109,8 +119,7 @@ def self_attention(p, x, pos, kv, *, heads, kv_heads, dh, window, theta,
                         causal=True, q_chunk=q_chunk,
                         logits_dtype=logits_dtype)
         if paged:
-            k2 = cache_lib.paged_scatter(kv["k"], kv["btab"], k, pos)
-            v2 = cache_lib.paged_scatter(kv["v"], kv["btab"], v, pos)
+            k2, v2 = scatter(kv["k"], k), scatter(kv["v"], v)
         else:
             k2, v2, _ = cache_lib.update_kv(kv["k"], kv["v"], kv["pos"],
                                             k, v, pos)
@@ -118,10 +127,8 @@ def self_attention(p, x, pos, kv, *, heads, kv_heads, dh, window, theta,
     else:  # decode: update-then-attend
         pos2 = cache_lib.scatter_ring(kv["pos"], pos, pos)
         if paged:
-            k2 = cache_lib.paged_scatter(kv["k"], kv["btab"], k, pos)
-            v2 = cache_lib.paged_scatter(kv["v"], kv["btab"], v, pos)
-            gk = cache_lib.paged_gather(k2, kv["btab"])
-            gv = cache_lib.paged_gather(v2, kv["btab"])
+            k2, v2 = scatter(kv["k"], k), scatter(kv["v"], v)
+            gk, gv = gather(k2), gather(v2)
         else:
             k2 = cache_lib.scatter_ring(kv["k"], k, pos)
             v2 = cache_lib.scatter_ring(kv["v"], v, pos)

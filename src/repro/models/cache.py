@@ -7,12 +7,18 @@ group).  KV entries come in two storage layouts:
   window): sliding-window layers keep only their window, global layers
   the full sequence.
 * **paged** — a *shared* physical pool of fixed-size blocks
-  ((count, num_blocks, block_size, ...)) plus a per-slot block table
+  ((count, num_blocks, block_size, *row); ``pool_row`` gives a token's
+  row, (kv_heads, head_dim) or flat) plus a per-slot block table
   ``btab`` (B, max_blocks) mapping logical block -> physical block (-1 =
   unleased).  Slots lease blocks on demand (see repro/serve/pool.py)
   instead of reserving ``max_len`` rings up front; the attention path
   gathers/scatters through the table.  Used for full-length entries
-  where the dense reservation is the memory cost worth paging.
+  where the dense reservation is the memory cost worth paging.  The
+  pool stays stacked over the layers: the layer scan carries it whole
+  and each layer reads and writes its own blocks by layer index
+  (``paged_gather``/``paged_scatter``), so no layer's pool is sliced
+  out, restacked or copied.  The drop/fill sentinel row lies beyond the
+  whole stacked pool.
 
 Slot positions are tracked explicitly in ``pos`` (shape (B, L), -1 =
 empty) so attention masks are always derived from true token positions —
@@ -117,66 +123,79 @@ def update_kv(entry_k: Array, entry_v: Array, pos: Array,
 
 
 # --- paged entries ---------------------------------------------------------
+#
+# A paged entry's pool stays stacked, (count, num_blocks, bs, *row), and
+# is read and written by layer index: the layer scan carries the whole
+# pool and updates it in place, never slicing out one layer's blocks.
+# Both ops address rows of the flattened stacked pool, row
+# ``(layer * num_blocks + block) * bs + offset``; the row for a dropped
+# write or an unleased read is ``count * num_blocks * bs``, beyond the
+# whole stacked pool, so it can never name a row of another layer.
+
+#: lanes of the TPU's minor tile: an array's minor dimension is laid out
+#: in whole units of 128 elements
+LANES = 128
 
 
-def _flat_pool(buf: Array) -> Array:
-    """(num_blocks, bs, ...) physical pool -> (num_blocks * bs, ...)."""
-    return buf.reshape((buf.shape[0] * buf.shape[1],) + buf.shape[2:])
+def pool_row(kv_heads: int, head_dim: int) -> Tuple[int, ...]:
+    """Trailing shape of one token's row in a paged pool.
+
+    (kv_heads, head_dim) when head_dim fills whole lane tiles; else the
+    flat kv_heads * head_dim.  A minor dimension that pads its tile
+    makes the device lay the pool out with the block axis minor, and the
+    layer scan would then relayout the whole pool on every tick."""
+    if head_dim % LANES == 0:
+        return (kv_heads, head_dim)
+    return (kv_heads * head_dim,)
 
 
-def paged_gather(buf: Array, btab: Array) -> Array:
-    """Materialize the logical per-slot view of a paged pool.
+def _flat_pool(pool: Array) -> Array:
+    """(count, num_blocks, bs, *row) stacked pool -> (count * num_blocks *
+    bs, *row) rows; merges major axes only, so no data moves."""
+    return pool.reshape((-1,) + pool.shape[3:])
 
-    buf: (num_blocks, bs, H, dh) one layer's physical pool;
-    btab: (B, M) block table.  Returns (B, M * bs, H, dh) where logical
-    token position p of slot b lives at index p; unleased blocks read as
-    zeros (their ``pos`` entries are -1, so attention masks them out).
+
+def paged_gather(pool: Array, btab: Array, layer: Array) -> Array:
+    """Materialize layer ``layer``'s logical per-slot view of a paged pool.
+
+    pool: (count, num_blocks, bs, *row) stacked physical pool; btab:
+    (B, M) block table; layer: scalar layer index.  Returns (B, M * bs,
+    *row) where logical token position p of slot b lives at index p;
+    unleased blocks read as zeros (their ``pos`` entries are -1, so
+    attention masks them out).  One gather from the stacked pool.
     """
     with jax.named_scope("paged_gather"):
-        bs = buf.shape[1]
-        flat = _flat_pool(buf)
-        base = jnp.where(btab >= 0, btab * bs, flat.shape[0])  # OOB => fill
+        nb, bs = pool.shape[1:3]
+        flat = _flat_pool(pool)
+        base = jnp.where(btab >= 0, (layer * nb + btab) * bs,
+                         flat.shape[0])                 # past the pool => 0
         idx = base[:, :, None] + jnp.arange(bs, dtype=btab.dtype)[None, None]
         idx = idx.reshape(btab.shape[0], -1)
         return jnp.take(flat, idx, axis=0, mode="fill", fill_value=0)
 
 
-def paged_scatter(buf: Array, btab: Array, new: Array, q_pos: Array
-                  ) -> Array:
-    """Write chunk tokens into the physical pool through the block table.
+def paged_scatter(pool: Array, btab: Array, layer: Array, new: Array,
+                  q_pos: Array) -> Array:
+    """Write chunk tokens into layer ``layer`` of the stacked pool through
+    the block table, with one scatter on the whole pool.
 
-    buf: (num_blocks, bs, H, dh); btab: (B, M); new: (B, C, H, dh);
-    q_pos: (B, C) logical positions (-1 = padding).  Tokens whose
-    position is invalid or whose logical block is unleased are dropped —
-    they can never land in another slot's blocks.
+    pool: (count, num_blocks, bs, *row); btab: (B, M); layer: scalar;
+    new: (B, C, H, dh), written as rows of ``pool``'s row shape; q_pos:
+    (B, C) logical positions (-1 = padding).
+    Tokens whose position is invalid or whose logical block is unleased
+    are dropped: they land in no layer and in no other slot's blocks.
     """
     with jax.named_scope("paged_scatter"):
-        bs = buf.shape[1]
-        flat = _flat_pool(buf)
-        size = flat.shape[0]
+        nb, bs = pool.shape[1:3]
+        flat = _flat_pool(pool)
         lb = jnp.where(q_pos >= 0, q_pos // bs, 0)
         blk = jnp.take_along_axis(btab, lb, axis=1)           # (B, C)
-        phys = jnp.where((q_pos >= 0) & (blk >= 0),
-                         blk * bs + q_pos % bs, size)         # size => drop
-        flat = flat.at[phys.reshape(-1)].set(
-            new.reshape((-1,) + new.shape[2:]), mode="drop")
-        return flat.reshape(buf.shape)
-
-
-def paged_kv_entry(count: int, num_blocks: int, block_size: int,
-                   batch: int, max_len: int, kv_heads: int, head_dim: int,
-                   dtype=jnp.bfloat16) -> Dict[str, Array]:
-    """A paged KV entry: shared physical pool + per-slot block table."""
-    M = -(-max_len // block_size)
-    L = M * block_size
-    return {
-        "k": jnp.zeros((count, num_blocks, block_size, kv_heads, head_dim),
-                       dtype),
-        "v": jnp.zeros((count, num_blocks, block_size, kv_heads, head_dim),
-                       dtype),
-        "pos": jnp.full((batch, L), -1, jnp.int32),
-        "btab": jnp.full((batch, M), -1, jnp.int32),
-    }
+        row = jnp.where((q_pos >= 0) & (blk >= 0),
+                        (layer * nb + blk) * bs + q_pos % bs,
+                        flat.shape[0])                  # past the pool => drop
+        flat = flat.at[row.reshape(-1)].set(
+            new.reshape((-1,) + pool.shape[3:]), mode="drop")
+        return flat.reshape(pool.shape)
 
 
 def cache_len_for(window: int, max_len: int) -> int:

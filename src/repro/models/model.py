@@ -237,8 +237,9 @@ class Model:
             if paged is not None and wl >= max_len:
                 # page exactly the entries whose dense form reserves the
                 # full max_len; windowed rings are already proportional
-                out["k"] = ((g.count, paged.num_blocks, paged.block_size,
-                             cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+                out["k"] = ((g.count, paged.num_blocks, paged.block_size)
+                            + cache_lib.pool_row(cfg.num_kv_heads,
+                                                 cfg.head_dim), jnp.bfloat16)
                 out["v"] = out["k"]
                 out["pos"] = ((batch, paged.logical_len(max_len)),
                               jnp.int32)
@@ -356,7 +357,7 @@ class Model:
         if mode != "train":
             kv = {"k": entry["k"], "v": entry["v"], "pos": entry["pos"]}
             if "btab" in entry:
-                kv["btab"] = entry["btab"]
+                kv["btab"], kv["layer"] = entry["btab"], entry["layer"]
         o, new_kv = blocks.self_attention(
             p, h, pos, kv, window=window, theta=s.theta, mode=mode,
             q_chunk=self.q_chunk, logits_dtype=self.logits_dtype, **dims)
@@ -381,80 +382,78 @@ class Model:
 
     def _run_group(self, g: GroupDef, gparams, h, entries, pos, ctx, mode):
         """entries: dict sub_idx -> cache entry (with group-level 'pos'
-        threaded in).  Returns (h, new entries, aux)."""
+        threaded in).  Returns (h, new entries, aux).
+
+        Parameters, ring cache leaves and the window array are scanned
+        per layer (``xs``/``ys``).  'pos' and 'btab' are group-level
+        (identical for every layer) and threaded around the scan.  A
+        paged entry's stacked pool 'k'/'v' rides in the carry whole and
+        each layer updates it in place at its index (``xs['layer']``), so
+        the pool is never sliced per layer, restacked or copied."""
         cfg = self.cfg
         train = mode == "train"
-        # per-layer xs: params + scanned cache leaves + window array.
-        # 'pos' and 'btab' are group-level (identical for every layer in
-        # the scan) and threaded around it, not through it.
-        cache_xs = ()
-        if not train:
-            cache_xs = tuple(
-                {k: v for k, v in entries[si].items()
-                 if k not in ("pos", "btab")}
-                for si in range(len(g.subs)))
-        warr = jnp.asarray(g.window_array, jnp.int32) if g.window_array \
-            else None
-        pos_by_sub = [entries[si].get("pos") if not train else None
-                      for si in range(len(g.subs))]
-        btab_by_sub = [entries[si].get("btab") if not train else None
-                       for si in range(len(g.subs))]
+        subs = range(len(g.subs))
+        ent = [{} if train else entries[si] for si in subs]
+        paged = [not train and "btab" in e for e in ent]
+
+        def scanned(e, pg):
+            """The leaves of entry ``e`` that the scan takes per layer."""
+            return {k: v for k, v in e.items() if k not in ("pos", "btab")
+                    and not (pg and k in ("k", "v"))}
+
+        cache_xs = tuple(scanned(e, pg) for e, pg in zip(ent, paged)) \
+            if not train else ()
+        pools = tuple({"k": e["k"], "v": e["v"]} if pg else None
+                      for e, pg in zip(ent, paged)) if not train else ()
+        xs = {"p": gparams, "c": cache_xs,
+              "w": jnp.asarray(g.window_array, jnp.int32)
+              if g.window_array else None,
+              "layer": jnp.arange(g.count, dtype=jnp.int32)
+              if any(paged) else None}
 
         def body(carry, xs):
-            h, aux = carry
-            if warr is not None:
-                if train:
-                    ps, wv = xs
-                    cs = ()
-                else:
-                    ps, cs, wv = xs
-            else:
-                wv = None
-                if train:
-                    ps = xs
-                    cs = ()
-                else:
-                    ps, cs = xs
-            new_cs = []
+            h, aux, pools = carry
+            new_cs, new_pools = [], []
             for si, s in enumerate(g.subs):
                 entry = None
                 if not train:
-                    entry = dict(cs[si])
-                    if pos_by_sub[si] is not None:
-                        entry["pos"] = pos_by_sub[si]
-                    if btab_by_sub[si] is not None:
-                        entry["btab"] = btab_by_sub[si]
-                h, new, a = self._apply_sub(s, ps[si], h, entry, pos, ctx,
-                                            mode, window_override=wv)
+                    entry = dict(xs["c"][si])
+                    if "pos" in ent[si]:
+                        entry["pos"] = ent[si]["pos"]
+                    if paged[si]:
+                        entry.update(pools[si], btab=ent[si]["btab"],
+                                     layer=xs["layer"])
+                h, new, a = self._apply_sub(s, xs["p"][si], h, entry, pos,
+                                            ctx, mode,
+                                            window_override=xs["w"])
                 aux = aux + a
                 if not train:
-                    new_cs.append({k: v for k, v in (new or {}).items()
-                                   if k not in ("pos", "btab")})
-            return (h, aux), tuple(new_cs)
+                    new = new or {}
+                    new_pools.append({"k": new["k"], "v": new["v"]}
+                                     if paged[si] else None)
+                    new_cs.append(scanned(new, paged[si]))
+            return (h, aux, tuple(new_pools)), tuple(new_cs)
 
         if cfg.remat:
             policy = None
             if cfg.remat_policy == "dots":
                 policy = jax.checkpoint_policies.dots_saveable
             body = jax.checkpoint(body, policy=policy)
-        if warr is not None:
-            xs = (gparams, warr) if train else (gparams, cache_xs, warr)
-        else:
-            xs = gparams if train else (gparams, cache_xs)
-        (h, aux), new_cache_xs = lax.scan(body, (h, jnp.zeros((), jnp.float32)),
-                                          xs)
+        (h, aux, pools), new_cache_xs = lax.scan(
+            body, (h, jnp.zeros((), jnp.float32), pools), xs)
         new_entries = {}
         if not train:
-            for si, s in enumerate(g.subs):
-                ent = dict(new_cache_xs[si])
-                if pos_by_sub[si] is not None:
+            for si in subs:
+                new = dict(new_cache_xs[si])
+                if paged[si]:
+                    new.update(pools[si])
+                    new["btab"] = ent[si]["btab"]  # host-leased, read-only
+                if "pos" in ent[si]:
                     # group-level position update (same for all layers);
                     # masked scatter drops padded (-1) positions
-                    ent["pos"] = cache_lib.scatter_ring(
-                        pos_by_sub[si], pos, pos)
-                if btab_by_sub[si] is not None:
-                    ent["btab"] = btab_by_sub[si]   # host-leased, read-only
-                new_entries[si] = ent
+                    new["pos"] = cache_lib.scatter_ring(
+                        ent[si]["pos"], pos, pos)
+                new_entries[si] = new
         return h, new_entries, aux
 
     def _encode(self, params, src_embeds):

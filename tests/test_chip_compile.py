@@ -51,15 +51,17 @@ def _bytes_on_device(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("tick", ["chunk", "decode"])
-def test_danube_serve_tick_fits_one_v5e(tick, one_chip, danube_engine):
+@pytest.fixture(scope="module", params=["chunk", "decode"])
+def danube_tick(request, one_chip, danube_engine):
+    """(tick kind, the danube tick program compiled for one v5e, the
+    paged pool's shape); compiled once per kind for this module."""
     import jax
     import jax.numpy as jnp
     from repro.serve.engine import split_btab
 
     eng = danube_engine
     B = eng.slots
-    width = eng.sched.chunk if tick == "chunk" else 1
+    width = eng.sched.chunk if request.param == "chunk" else 1
 
     def shaped(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -76,8 +78,45 @@ def test_danube_serve_tick_fits_one_v5e(tick, one_chip, danube_engine):
         arr((B,), jnp.bool_), arr((B,), jnp.int32), arr((B,), jnp.int32),
         arr((B,), jnp.float32), arr((2,), jnp.uint32), arr((B,), jnp.int32),
         shaped(cache), shaped(btab)).compile()
+    pool = cache[eng.paged_entries[0]]["k"].shape
+    return request.param, compiled, pool
+
+
+def test_danube_serve_tick_fits_one_v5e(danube_tick):
+    tick, compiled, _ = danube_tick
     used = _bytes_on_device(compiled)
     assert 0 < used < HBM_BYTES, f"{tick} tick needs {used / 1e9:.2f} GB"
+
+
+def _pool_moves(hlo: str, pool) -> list:
+    """Instructions of ``hlo`` that slice, update or copy a whole layer's
+    pool or the whole stacked pool, (count, num_blocks, bs, *row), as it
+    is or with its leading axes merged."""
+    import re
+
+    count, nb, bs, row = pool[0], pool[1], pool[2], tuple(pool[3:])
+    shapes = {"bf16[" + ",".join(map(str, s)) + "]"
+              for n in (1, count)
+              for s in ((n, nb, bs) + row, (n * nb, bs) + row,
+                        (n * nb * bs,) + row)}
+    found = []
+    for name, shape, op in re.findall(
+            r"%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(", hlo):
+        moves = any(w in name or w in op for w in
+                    ("dynamic-slice", "dynamic-update-slice", "copy"))
+        if moves and shape in shapes:
+            found.append(f"{name} = {shape} {op}")
+    return found
+
+
+def test_danube_tick_keeps_the_paged_pool_in_place(danube_tick):
+    """The layer scan carries the stacked pool and updates it in place:
+    no layer's pool is sliced out or written back, and the donated pool
+    is not copied, inside the loop or around it."""
+    tick, compiled, pool = danube_tick
+    assert len(pool) >= 4 and pool[0] == 24
+    moves = _pool_moves(compiled.as_text(), pool)
+    assert not moves, f"{tick} tick moves the pool: {moves}"
 
 
 @pytest.mark.parametrize("transport,op", [("native", "allreduce"),
