@@ -11,10 +11,12 @@ the same number for the control on the same sample (its upper end),
 each put through the cell's own ``Check`` with its limit, so the line
 shows whether it passes.
 
-* serving cells: the mean gap below the float32 reference's best of
-  the served tokens, and of the tokens that the reference run at float8
-  e4m3 puts first (the precision below the served bfloat16), with the
-  widest gap and the count of tokens off the reference's best;
+* serving cells (the cell's own kind, with its configuration's
+  reference module): the mean gap below the float32 reference's best
+  of the served tokens, and of the tokens that the reference's control
+  (``quant=True``, one precision below the served one) puts first,
+  with the widest gap and the count of tokens off the reference's
+  best;
 * Communicator cells: the answers computed in bfloat16, put on the
   mesh as the program's output would be and counted by the run's own
   ``mismatches`` against the float32 answers.
@@ -43,8 +45,8 @@ from chipbench.run import enable_cache  # noqa: E402
 
 
 def serve_readings(cell, seed: int, seconds: float, devices) -> dict:
-    serve = load_module(BENCH / "kinds" / "serve.py")
     conf, mix = cell.config, cell.traffic
+    serve = load_module(BENCH / "kinds" / f"{conf['kind']}.py")
     limit = conf["check"]["max_mean_logit_gap"]
     weights = serve.make_weights(conf, seed)
     eng = serve.build(conf, weights, devices)
@@ -105,10 +107,10 @@ def main() -> None:
     enable_cache()
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.perf_counter()
-        if cell.config["kind"] == "serve":
-            out = serve_readings(cell, seed, args.seconds, devices)
-        else:
+        if cell.config["kind"] == "comm":
             out = comm_readings(cell, seed, devices)
+        else:
+            out = serve_readings(cell, seed, args.seconds, devices)
         print(json.dumps({"workload": cell.name, "seed": seed,
                           "wall_s": time.perf_counter() - t, **out}),
               flush=True)

@@ -89,11 +89,16 @@ def tick_texts(eng) -> Dict[str, str]:
     return out
 
 
-def pool_dims(conf: dict):
-    """(blocks, block size, KV heads, head width) of the paged pool."""
-    s, m = conf["serving"], conf["model"]
-    blocks = s["slots"] * -(-s["max_len"] // s["block_size"])
-    return blocks, s["block_size"], m["num_kv_heads"], m["head_dim"]
+def pool_dims(eng):
+    """One layer's shape of the paged pool, (blocks, block size, *row),
+    from the engine's cache specs: ``cache.pool_row`` may store a row
+    flat (danube: 640) or as (KV heads, head width) (deepseek: 32,
+    128).  None where no entry is paged."""
+    if not eng.paged_entries:
+        return None
+    specs = eng.model.cache_specs(eng.slots, eng.max_len,
+                                  paged=eng.page_spec)
+    return tuple(specs[eng.paged_entries[0]]["k"].shape[1:])
 
 
 def ranges(ids):
@@ -140,12 +145,12 @@ def trace_readings(trace: tl.Trace, prog: et.ProgramTrace,
 def readings(cell, args, devices, peaks) -> dict:
     from chipbench.run import per_layer
     import jax
-    serve = load_module(BENCH / "kinds" / "serve.py")
     conf, mix = cell.config, cell.traffic
+    serve = load_module(BENCH / "kinds" / f"{conf['kind']}.py")
     weights = jax.block_until_ready(serve.make_weights(conf, args.seed))
     eng = serve.build(conf, weights, devices)
     serve.warm_up(eng)
-    texts = tick_texts(eng)
+    texts, dims = tick_texts(eng), pool_dims(eng)
     reqs = traffic_lib.generate(mix, args.seed, args.seconds,
                                 conf["model"]["vocab_size"])
     tracer = SnapshotAtStart(serve.Tracer(
@@ -157,7 +162,8 @@ def readings(cell, args, devices, peaks) -> dict:
     e2e = serve.window_metrics(eng, due, args.seconds)
     stats = getattr(eng, "stats", None)
     records = dict(getattr(eng, "_metrics", {}))
-    srun = serve.ServeRun(conf["model"], peaks, list(eng.ticks),
+    srun = serve.ServeRun(conf["model"], serve.reference_of(conf), peaks,
+                          list(eng.ticks),
                           serve.inflight_intervals(eng, args.seconds))
     del eng
     gc.collect()
@@ -181,7 +187,7 @@ def readings(cell, args, devices, peaks) -> dict:
                         per_layer(cell, outcome).items()}
     out["per_layer"]["idle_share"] = 1.0 - outcome.busy_s / outcome.window_s
     out["breakdown_idle_gaps"] = outcome.breakdown["idle_gaps"][:3]
-    engine.update(trace_readings(srun.trace, prog, texts, pool_dims(conf),
+    engine.update(trace_readings(srun.trace, prog, texts, dims,
                                  srun.lo_ns, srun.hi_ns))
     return out
 

@@ -8,6 +8,12 @@ benchmark's wrapper on ``sched.plan`` ends it at ``--seconds`` by
 raising, so no drain is timed and a request in flight counts only the
 tokens it produced inside the window.
 
+The architecture lives in the configuration's ``reference`` module
+(``references/<name>.py``; ``references/dense_decoder.py`` states its
+contract): this kind takes from it the weights, the program's parameter
+tree for them, the reference logits and the FLOP and byte counts that
+the per-layer readers use, and names no weight or layer of its own.
+
 The benchmark's host spans (``cb:plan``, ``cb:dispatch.<kind>``,
 ``cb:finish``, ``cb:admit``) sit around the engine's calls into each
 layer; they are ``TraceAnnotation``s, on the device trace's clock.
@@ -24,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import shutil
-from typing import Dict, List, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import numpy as np
@@ -124,17 +130,6 @@ def arch_config(conf: dict) -> ArchConfig:
         base, prefill_chunk=conf["serving"]["prefill_chunk"], **sizes)
 
 
-def program_params(w: dict) -> dict:
-    """The benchmark's weights in the program's parameter layout (the
-    same arrays, no copy)."""
-    layer = {"norm": {"scale": w["attn_norm"]}, "wq": w["wq"],
-             "wk": w["wk"], "wv": w["wv"], "wo": w["wo"],
-             "fnorm": {"scale": w["ffn_norm"]},
-             "ffn": {"w1": w["w1"], "w3": w["w3"], "w2": w["w2"]}}
-    return {"emb": w["embed"], "final_norm": {"scale": w["final_norm"]},
-            "unemb": w["lm_head"], "dec_dec": (layer,)}
-
-
 def reference_of(conf: dict):
     return load_module(BENCH / "references" / f"{conf['reference']}.py")
 
@@ -150,7 +145,7 @@ def build(conf: dict, weights, devices) -> BenchEngine:
     eng = BenchEngine(arch_config(conf), mesh_for_devices(len(devices)),
                       slots=s["slots"], max_len=s["max_len"],
                       block_size=s["block_size"], cache_mode="paged")
-    eng.load(program_params(weights))
+    eng.load(reference_of(conf).program_params(weights, conf["model"]))
     return eng
 
 
@@ -274,11 +269,9 @@ def reference_gaps(conf: dict, weights, sample, n: int,
         seq = np.concatenate([r.prompt, s[:-1]])
         toks[b, :len(seq)] = seq
         at[b] = len(r.prompt) - 1 + np.minimum(np.arange(n), len(s) - 1)
-    model = {k: v for k, v in conf["model"].items()
-             if isinstance(v, (int, float))}
-    lg = ref.logits_at(model, weights, toks, at)
-    ctl = ref.logits_at(model, weights, toks, at, quant=True) if quant \
-        else None
+    lg = ref.logits_at(conf["model"], weights, toks, at)
+    ctl = ref.logits_at(conf["model"], weights, toks, at, quant=True) \
+        if quant else None
     lg = np.asarray(lg)
     top = lg.max(-1)
     served, control = [], []
@@ -310,6 +303,7 @@ def gap_stats(gaps) -> dict:
 class ServeRun:
     """What the per-layer readers read."""
     model: dict
+    counts: Any                           # the reference module
     peaks: dict
     ticks: List[Tick]
     inflight: List[Tuple[float, float]]   # host seconds, >= 1 request
@@ -384,8 +378,8 @@ def run(cell, args, devices, peaks, phases: Phases) -> Outcome:
         f" chunk), {len(eng.finished)} finished, "
         f"{window_compiles.count} compiles inside the window")
     mem = peak_bytes(devices)
-    srun = ServeRun(conf["model"], peaks, list(eng.ticks),
-                    inflight_intervals(eng, args.seconds))
+    srun = ServeRun(conf["model"], reference_of(conf), peaks,
+                    list(eng.ticks), inflight_intervals(eng, args.seconds))
     sample = served_sample(eng, reqs, args.seed, conf["check"]["rows"])
     del eng
     gc.collect()

@@ -40,7 +40,7 @@ def main() -> None:
     cell = find_cell(read_json(ROOT / "BENCHMARK.json"), args.workload)
     devices = require_devices(cell.chips)
     enable_cache()
-    serve = load_module(BENCH / "kinds" / "serve.py")
+    serve = load_module(BENCH / "kinds" / f"{cell.config['kind']}.py")
     conf = cell.config
     weights = serve.make_weights(conf, args.seed)
     for rate in [float(r) for r in args.rates.split(",")]:

@@ -1,18 +1,11 @@
-"""Operations and bytes that a step needs, computed from shapes.
+"""Counts that hold for every architecture, computed from shapes.
 
 These count what the algorithm requires, whatever implements it: a copy
 the program makes on top is not counted, so it shows as a lower share of
-the roofline.
-
-Serving (dense decoder, ``model`` is a configuration's ``model`` dict):
-
-* a token costs 2 FLOPs per matmul weight of the layers, plus attention
-  over its live context: 4 * heads * head_dim FLOPs per key per layer
-  (scores and the weighted sum);
-* a row that samples costs the LM head, 2 * d_model * vocab;
-* a decode tick reads every weight once (layers, final norm and LM
-  head; the embedding only for its rows) and the K/V of every live
-  token, and writes the new token's K/V.
+the roofline.  What one architecture's step needs (its FLOPs per tick,
+its bytes per decode tick) is counted by its configuration's reference
+module (``references/dense_decoder.py`` states the contract), from
+these helpers.
 
 Exchanges (``op`` on ``n`` ranks, ``size`` bytes per rank): the bytes
 one chip must move for the operation's semantics, never what a
@@ -20,16 +13,7 @@ transport happens to send.
 """
 from __future__ import annotations
 
-from typing import Iterable, Tuple
-
 BF16 = 2
-
-
-def layer_weights(model: dict) -> int:
-    """Matmul weights of one decoder layer."""
-    D, H, K, dh, F = (model["d_model"], model["num_heads"],
-                      model["num_kv_heads"], model["head_dim"], model["d_ff"])
-    return D * H * dh + 2 * D * K * dh + H * dh * D + 3 * D * F
 
 
 def context(start: int, length: int, window: int) -> int:
@@ -42,37 +26,6 @@ def context(start: int, length: int, window: int) -> int:
     if below_hi >= lo:
         part = (lo + below_hi) * (below_hi - lo + 1) // 2
     return part + full * window
-
-
-def tick_flops(model: dict, rows: Iterable[Tuple[int, int]]) -> float:
-    """Model FLOPs of one serving tick; ``rows`` holds (start, length)
-    of every row that carries tokens (each samples one token)."""
-    L, H, dh, D, V = (model["num_layers"], model["num_heads"],
-                      model["head_dim"], model["d_model"],
-                      model["vocab_size"])
-    W = model.get("sliding_window") or (1 << 30)
-    per_token = 2 * L * layer_weights(model)
-    flops = 0
-    for start, n in rows:
-        flops += n * per_token + 2 * D * V
-        flops += 4 * L * H * dh * context(start, n, W)
-    return float(flops)
-
-
-def decode_bytes(model: dict, rows: Iterable[Tuple[int, int]]) -> float:
-    """HBM bytes one decode tick needs: weights once, the K/V of each
-    row's live context read, and its new K/V written."""
-    L, K, dh, D, V = (model["num_layers"], model["num_kv_heads"],
-                      model["head_dim"], model["d_model"],
-                      model["vocab_size"])
-    W = model.get("sliding_window") or (1 << 30)
-    weights = (L * (layer_weights(model) + 2 * D) + D + D * V) * BF16
-    kv_token = L * 2 * K * dh * BF16
-    total = weights
-    for start, n in rows:
-        total += D * BF16 * n                           # embedding rows
-        total += kv_token * (context(start, n, W) + n)
-    return float(total)
 
 
 def least_time(flops: float, nbytes: float, peaks: dict) -> float:

@@ -1,8 +1,8 @@
 """Least time of the decode ticks over their device time, in percent.
 A tick's least time is max(FLOPs / bf16 peak, bytes / HBM bandwidth),
-with bytes = every weight once + the K/V of its live tokens
-(``lib/flops.py``); device time is its program's execution in the
-trace."""
+with FLOPs and bytes (every weight once + the cache of its live tokens)
+counted by the configuration's reference module; device time is its
+program's execution in the trace."""
 from chipbench.lib import flops
 
 
@@ -11,8 +11,9 @@ def read(run):
              if t.kind == "decode"]
     if not pairs:
         return None
-    least = sum(flops.least_time(flops.tick_flops(run.model, t.rows),
-                                 flops.decode_bytes(run.model, t.rows),
+    counts, model = run.counts, run.model
+    least = sum(flops.least_time(counts.tick_flops(model, t.rows),
+                                 counts.decode_bytes(model, t.rows),
                                  run.peaks) for t, _ in pairs)
     device = sum(e.dur for _, e in pairs) / 1e9
     return 100.0 * least / device

@@ -1,6 +1,6 @@
 """Model FLOPs of the prompt and decode tokens of the ticks dispatched
-in the traced window, over the window and the chip's bf16 peak."""
-from chipbench.lib import flops
+in the traced window, counted by the configuration's reference module,
+over the window and the chip's bf16 peak."""
 
 
 def read(run):
@@ -10,5 +10,5 @@ def read(run):
     window = (run.hi_ns - run.lo_ns) / 1e9
     if not ticks or window <= 0:
         return None
-    work = sum(flops.tick_flops(run.model, t.rows) for t in ticks)
+    work = sum(run.counts.tick_flops(run.model, t.rows) for t in ticks)
     return 100.0 * work / window / run.peaks["bf16_flops_per_s"]

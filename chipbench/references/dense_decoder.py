@@ -7,23 +7,58 @@ published descriptions (Llama, arXiv:2302.13971; Mistral's window,
 arXiv:2310.06825), in ``jax.numpy`` at float32 with every matmul at
 ``Precision.HIGHEST``; it imports nothing of the system under test.
 
-The weights are the benchmark's: ``init_weights`` makes them on the
-device from a key, in bfloat16 (the served type), and the same arrays
-feed the program and this reference.
+A serving configuration's ``reference`` module is the one place, on the
+benchmark's side, where its architecture lives: ``kinds/serve.py``,
+the per-layer readers and ``control.py`` reach the architecture only
+through these five functions, so a new architecture is a new module
+beside this one.  ``model`` is always the configuration's ``model``
+dict as written; what ``jit`` needs hashable, the module makes so.
 
-``quant=True`` is the control: every matmul operand is rounded to
-float8 e4m3 with an absmax scale per row of the activation and per
-output column of the weight, then multiplied at float32 -- the step
-below the bfloat16 that the configuration serves in.
+* ``init_weights(model, key)``: the benchmark's weights, made on the
+  device from a key in the served type (one jitted call);
+* ``program_params(w, model)``: the program's parameter tree for those
+  weights, the same arrays.  The dense tree ignores ``model``; a tree
+  whose groups follow the configuration (a dense first layer before
+  mixture-of-experts layers, the experts a chip holds) needs it;
+* ``logits_at(model, w, tokens, at, quant=False)``: float32 logits at
+  positions ``at`` of the sequences ``tokens``; ``quant=True`` is the
+  control, the reference one precision below the served one;
+* ``tick_flops(model, rows)`` and ``decode_bytes(model, rows)``: the
+  model FLOPs of a serving tick and the HBM bytes a decode tick needs,
+  for ``rows``, the (start, length) of every row that carries tokens
+  (each samples one token).  They count what the algorithm requires,
+  whatever implements it (``lib/flops.py``).
+
+``mm``, ``rms``, ``rope`` and ``attention`` are this module's layer
+arithmetic (float32 at ``HIGHEST``; ``quant=True`` through ``mm`` and
+``attention``), public so that another reference module may build its
+layers from them; a change here changes those modules too.
+
+Here, the weights are normal and scaled by fan_in ** -0.5, in bfloat16,
+and the same arrays feed the program and this reference.
+
+``quant=True`` rounds every matmul operand to float8 e4m3 with an absmax
+scale per row of the activation and per output column of the weight,
+then multiplies at float32 -- the step below the bfloat16 that the
+configuration serves in.
+
+Counts: a token costs 2 FLOPs per matmul weight of the layers, plus
+attention over its live context, 4 * heads * head_dim FLOPs per key per
+layer (scores and the weighted sum); a row that samples costs the LM
+head, 2 * d_model * vocab.  A decode tick reads every weight once
+(layers, final norm and LM head; the embedding only for its rows) and
+the K/V of every live token, and writes the new token's K/V.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Dict, Iterable, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from chipbench.lib.flops import BF16, context
 
 HIGHEST = lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -62,19 +97,19 @@ def _q8(x, axis):
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _mm(x, w, quant: bool):
+def mm(x, w, quant: bool):
     x, w = x.astype(jnp.float32), w.astype(jnp.float32)
     if quant:
         x, w = _q8(x, -1), _q8(w, 0)
     return jnp.matmul(x, w, precision=HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * g.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """Rotate-half RoPE; x (B, T, H, dh), pos (B, T)."""
     half = x.shape[-1] // 2
     inv = theta ** -(jnp.arange(half, dtype=jnp.float32) / half)
@@ -84,7 +119,7 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
 
 
-def _attention(q, k, v, window, quant):
+def attention(q, k, v, window, quant):
     """Causal attention within ``window``; q (B,T,H,dh), k/v (B,T,K,dh).
     Query blocks of ``Q_BLOCK`` bound the score buffer."""
     B, T, H, dh = q.shape
@@ -125,23 +160,23 @@ def _layer(h, w, i, *, m, quant):
     lw = {n: w[n][i] for n in ("attn_norm", "wq", "wk", "wv", "wo",
                                 "ffn_norm", "w1", "w3", "w2")}
     pos = jnp.broadcast_to(jnp.arange(T), (B, T))
-    x = _rms(h, lw["attn_norm"], eps)
-    q = _rope(_mm(x, lw["wq"], quant).reshape(B, T, H, dh), pos, theta)
-    k = _rope(_mm(x, lw["wk"], quant).reshape(B, T, K, dh), pos, theta)
-    v = _mm(x, lw["wv"], quant).reshape(B, T, K, dh)
-    a = _attention(q, k, v, window, quant).reshape(B, T, H * dh)
-    h = h + _mm(a, lw["wo"], quant)
-    x = _rms(h, lw["ffn_norm"], eps)
-    return h + _mm(jax.nn.silu(_mm(x, lw["w1"], quant))
-                   * _mm(x, lw["w3"], quant), lw["w2"], quant)
+    x = rms(h, lw["attn_norm"], eps)
+    q = rope(mm(x, lw["wq"], quant).reshape(B, T, H, dh), pos, theta)
+    k = rope(mm(x, lw["wk"], quant).reshape(B, T, K, dh), pos, theta)
+    v = mm(x, lw["wv"], quant).reshape(B, T, K, dh)
+    a = attention(q, k, v, window, quant).reshape(B, T, H * dh)
+    h = h + mm(a, lw["wo"], quant)
+    x = rms(h, lw["ffn_norm"], eps)
+    return h + mm(jax.nn.silu(mm(x, lw["w1"], quant))
+                  * mm(x, lw["w3"], quant), lw["w2"], quant)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "quant"))
 def _head(h, at, w, *, m, quant):
     m = dict(m)
     hs = jnp.take_along_axis(h, at[..., None], axis=1)           # (B, n, D)
-    return _mm(_rms(hs, w["final_norm"], m["rms_norm_eps"]), w["lm_head"],
-               quant)
+    return mm(rms(hs, w["final_norm"], m["rms_norm_eps"]), w["lm_head"],
+              quant)
 
 
 def logits_at(m: dict, w, tokens, at, quant: bool = False):
@@ -153,3 +188,54 @@ def logits_at(m: dict, w, tokens, at, quant: bool = False):
     for i in range(m["num_layers"]):
         h = _layer(h, w, i, m=frozen, quant=quant)
     return _head(h, jnp.asarray(at), w, m=frozen, quant=quant)
+
+
+def program_params(w: dict, model: dict) -> dict:
+    """The benchmark's weights in the program's parameter layout (the
+    same arrays, no copy)."""
+    layer = {"norm": {"scale": w["attn_norm"]}, "wq": w["wq"],
+             "wk": w["wk"], "wv": w["wv"], "wo": w["wo"],
+             "fnorm": {"scale": w["ffn_norm"]},
+             "ffn": {"w1": w["w1"], "w3": w["w3"], "w2": w["w2"]}}
+    return {"emb": w["embed"], "final_norm": {"scale": w["final_norm"]},
+            "unemb": w["lm_head"], "dec_dec": (layer,)}
+
+
+# ------------------------------------------------------------------ counts
+
+
+def layer_weights(model: dict) -> int:
+    """Matmul weights of one decoder layer."""
+    D, H, K, dh, F = (model["d_model"], model["num_heads"],
+                      model["num_kv_heads"], model["head_dim"], model["d_ff"])
+    return D * H * dh + 2 * D * K * dh + H * dh * D + 3 * D * F
+
+
+def tick_flops(model: dict, rows: Iterable[Tuple[int, int]]) -> float:
+    """Model FLOPs of one serving tick."""
+    L, H, dh, D, V = (model["num_layers"], model["num_heads"],
+                      model["head_dim"], model["d_model"],
+                      model["vocab_size"])
+    W = model.get("sliding_window") or (1 << 30)
+    per_token = 2 * L * layer_weights(model)
+    flops = 0
+    for start, n in rows:
+        flops += n * per_token + 2 * D * V
+        flops += 4 * L * H * dh * context(start, n, W)
+    return float(flops)
+
+
+def decode_bytes(model: dict, rows: Iterable[Tuple[int, int]]) -> float:
+    """HBM bytes one decode tick needs: weights once, the K/V of each
+    row's live context read, and its new K/V written."""
+    L, K, dh, D, V = (model["num_layers"], model["num_kv_heads"],
+                      model["head_dim"], model["d_model"],
+                      model["vocab_size"])
+    W = model.get("sliding_window") or (1 << 30)
+    weights = (L * (layer_weights(model) + 2 * D) + D + D * V) * BF16
+    kv_token = L * 2 * K * dh * BF16
+    total = weights
+    for start, n in rows:
+        total += D * BF16 * n                           # embedding rows
+        total += kv_token * (context(start, n, W) + n)
+    return float(total)

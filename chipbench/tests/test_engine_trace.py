@@ -205,3 +205,22 @@ def test_tiny_window_reads_the_engine_counters():
     traced = sum(b - a + 1 for a, b in eng["untraced_ticks"])
     assert 0 < traced <= sum(st["ticks"].values())
     assert out["end_to_end"]["tokens_per_s"] > 0
+
+
+@pytest.mark.parametrize("config,want", [
+    ("h2o-danube-1.8b", (2048, 16, 640)),          # head_dim 80: flat
+    ("deepseek-7b", (1536, 16, 32, 128))])
+def test_pool_dims_from_the_engine_cache_specs(config, want):
+    """One layer's shape of the paged pool of the cell's own engine
+    (built, not loaded: no weights and no cache are made)."""
+    from chipbench.lib.harness import BENCH, load_module, read_json
+    from repro.launch.mesh import mesh_for_devices
+    from repro.serve import Engine
+    er = load_module(BENCH / "engine_readings.py")
+    serve = load_module(BENCH / "kinds" / "serve.py")
+    conf = read_json(BENCH / "configs" / f"{config}.json")
+    s = conf["serving"]
+    eng = Engine(serve.arch_config(conf), mesh_for_devices(1),
+                 slots=s["slots"], max_len=s["max_len"],
+                 block_size=s["block_size"], cache_mode="paged")
+    assert er.pool_dims(eng) == want

@@ -1,11 +1,16 @@
-"""The FLOP and byte counts against hand counts at a toy size."""
+"""The FLOP and byte counts against hand counts at a toy size: the
+dense decoder's, from its reference module, and the helpers that hold
+for every architecture."""
 import itertools
 
 import pytest
 
 from chipbench.tests import tiny  # noqa: F401  (puts chipbench on the path)
 from chipbench.lib import flops
+from chipbench.lib.harness import BENCH, load_module
 from chipbench.peaks import PEAKS
+
+dense = load_module(BENCH / "references" / "dense_decoder.py")
 
 # L=2 layers, D=4, 2 query heads and 1 KV head of 2, d_ff 3, vocab 5:
 # per layer 4*2*2 (wq) + 2*4*1*2 (wk, wv) + 2*2*4 (wo) + 3*4*3 (ffn) = 84
@@ -14,7 +19,7 @@ TOY = {"num_layers": 2, "d_model": 4, "num_heads": 2, "num_kv_heads": 1,
 
 
 def test_layer_weights():
-    assert flops.layer_weights(TOY) == 84
+    assert dense.layer_weights(TOY) == 84
 
 
 def test_context_matches_the_sum():
@@ -26,20 +31,20 @@ def test_context_matches_the_sum():
 def test_prefill_row():
     # 3 tokens at positions 0..2: matmuls 3*2*2*84 = 1008, LM head
     # 2*4*5 = 40, attention over 1+2+3 keys: 4*L*H*dh*6 = 192
-    assert flops.tick_flops(TOY, [(0, 3)]) == 1008 + 40 + 192
+    assert dense.tick_flops(TOY, [(0, 3)]) == 1008 + 40 + 192
 
 
 def test_decode_row_and_window():
     # one token at position 5 sees 6 keys, or 4 under a window of 4
-    assert flops.tick_flops(TOY, [(5, 1)]) == 336 + 40 + 192
-    assert flops.tick_flops(dict(TOY, sliding_window=4),
+    assert dense.tick_flops(TOY, [(5, 1)]) == 336 + 40 + 192
+    assert dense.tick_flops(dict(TOY, sliding_window=4),
                             [(5, 1)]) == 336 + 40 + 128
 
 
 def test_decode_bytes():
     # weights (2*(84 + 2*4) + 4 + 4*5) * 2 B = 416; the embedding row
     # 4*2 = 8; K/V per token 2*2*1*2*2 B = 16, for 6 keys read + 1 written
-    assert flops.decode_bytes(TOY, [(5, 1)]) == 416 + 8 + 16 * 7
+    assert dense.decode_bytes(TOY, [(5, 1)]) == 416 + 8 + 16 * 7
 
 
 @pytest.mark.parametrize("op,want", [("send", 8), ("bcast", 6),

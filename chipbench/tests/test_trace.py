@@ -109,8 +109,10 @@ def test_recorded_excerpt_pairs_ticks_with_programs():
              "vocab_size": 32000, "sliding_window": 4096}
     ticks = [serve.Tick("decode", 1.0, 0.004, [(1000, 1)] * 32),
              serve.Tick("chunk", 2.0, 0.004, [(0, 256)] * 2)]
-    run = serve.ServeRun(model, {}, ticks, [], trace=tr, traced_from_s=0.0,
-                         lo_ns=span.start, hi_ns=span.end)
+    dense = load_module(BENCH / "references" / "dense_decoder.py")
+    run = serve.ServeRun(model, dense, {}, ticks, [], trace=tr,
+                         traced_from_s=0.0, lo_ns=span.start,
+                         hi_ns=span.end)
     pairs = run.tick_programs()
     assert [(t.kind, e.dur) for t, e in pairs] == [
         ("decode", 154301123.0), ("chunk", 507137963.0)]

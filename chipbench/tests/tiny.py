@@ -29,6 +29,13 @@ def serve_cell(config="h2o-danube-1.8b", traffic="chat-azure-conv",
                        "prefill_chunk": chunk}
     conf["check"]["rows"] = 2
     conf["trace_seconds"] = 1
+    return Cell("tiny-serve", 1, config, conf, traffic,
+                serve_mix(traffic, rate), [], [])
+
+
+def serve_mix(traffic="chat-azure-conv", rate=4.0) -> dict:
+    """A serving mix cut to prompts of 4-64 and outputs of 2-24 tokens,
+    which a cell of max_len 96 holds."""
     mix = copy.deepcopy(read_json(BENCH / "traffic" / f"{traffic}.json"))
     mix["prompt"].update(min=4, max=64)
     mix["output"].update(min=2, max=24)
@@ -36,7 +43,7 @@ def serve_cell(config="h2o-danube-1.8b", traffic="chat-azure-conv",
         mix["prompt"]["median"], mix["output"]["median"] = 24, 8
     if mix["arrival"] == "poisson":
         mix["rate_per_s"] = rate
-    return Cell("tiny-serve", 1, config, conf, traffic, mix, [], [])
+    return mix
 
 
 def comm_cell(sizes=(8, 8192), p2p=(16, 4096)) -> Cell:
